@@ -10,12 +10,14 @@ import itertools
 
 from repro.core.sim import SimParams, make_streams, run_sim
 from repro.core.types import SyncMode
+from repro.launch.compile_cache import enable_compile_cache
 from repro.workloads.ycsb import WORKLOADS
 
 N_KEYS = 1_000_000
 
 
 def main():
+    enable_compile_cache()
     grid = itertools.product(
         [24, 32, 48],        # mn_cap
         [3, 6],              # addr_atomic_cap

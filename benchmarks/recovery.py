@@ -56,6 +56,7 @@ from repro.core.engine import populate, store_init, store_view
 from repro.core.simnet import SimParams
 from repro.core.types import EngineConfig, IOMetrics, SyncMode
 from repro.dist import store as dstore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.recovery import (FailoverEvent, run_recovery, run_recovery_sharded,
                             time_to_repair)
 from repro.workloads.recovery import RECOVERY_SCENARIOS
@@ -158,6 +159,7 @@ def main():
                     help="comma-separated scenario subset")
     ap.add_argument("--path", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     path = args.path or ("BENCH_recovery.fast.json" if args.fast
                          else FULL_BASELINE)
     if args.fast and os.path.abspath(path) == os.path.abspath(FULL_BASELINE):

@@ -63,6 +63,7 @@ from repro.core.simnet import SimParams
 from repro.core.types import (EngineConfig, IOMetrics, SyncMode,
                               per_replica_bill)
 from repro.dist import store as dstore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.recovery import mn_crash, run_recovery_replicated, slice_stream
 from repro.stores import PointerArray
@@ -171,6 +172,7 @@ def main():
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--path", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     path = args.path or ("BENCH_replication.fast.json" if args.fast
                          else FULL_BASELINE)
     if args.fast and os.path.abspath(path) == os.path.abspath(FULL_BASELINE):

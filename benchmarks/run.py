@@ -34,6 +34,7 @@ from repro.core.engine import populate, store_init
 from repro.core.sim import SimParams, make_streams, run_sim
 from repro.core.types import EngineConfig, IOMetrics, OpKind, SyncMode
 from repro.dist import store as dstore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.stores import PointerArray, RaceHash, SmartART
 from repro.workloads.ycsb import (WORKLOADS, YCSB, generate_window_stream,
@@ -538,6 +539,7 @@ def main():
     ap.add_argument("--only", default="")
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(FIGS)
     unknown = [n for n in names if n not in FIGS]
     if unknown:

@@ -56,6 +56,7 @@ from repro.core.engine import populate, store_init
 from repro.core.simnet import SimParams
 from repro.core.types import EngineConfig, SyncMode
 from repro.dist import store as dstore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.workloads.openloop import (OpenLoopSpec, dense_repack,
                                       generate_openloop_stream,
@@ -344,6 +345,7 @@ def main():
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--path", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     bench_scale_json(fast=args.fast, path=args.path)
 
 

@@ -44,6 +44,7 @@ from repro.core.engine import populate, store_init
 from repro.core.simnet import SimParams
 from repro.core.types import EngineConfig, IOMetrics, OpKind, SyncMode
 from repro.dist import store as dstore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.workloads.dynamic import SCENARIOS
 
@@ -131,6 +132,7 @@ def main():
                     help="comma-separated scenario subset")
     ap.add_argument("--path", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     path = args.path or ("BENCH_scenarios.fast.json" if args.fast
                          else FULL_BASELINE)
     if args.fast and os.path.abspath(path) == os.path.abspath(FULL_BASELINE):

@@ -53,10 +53,7 @@ from repro.core.credits import CreditState, credit_init
 from repro.core.engine import Results, StoreState
 from repro.core.types import EngineConfig, IOMetrics, OpBatch, SyncMode
 
-try:  # jax >= 0.5 exposes the jaxpr types publicly
-    from jax.extend import core as jcore  # type: ignore
-except ImportError:  # jax 0.4.x: only the private module has them
-    from jax._src import core as jcore
+from jax.extend import core as jcore
 
 __all__ = [
     "ALLOWED_DTYPES", "FORBIDDEN_DTYPES", "CALLBACK_PRIMS", "COMM_PRIMS",
@@ -84,11 +81,7 @@ CALLBACK_PRIMS = frozenset({
 COMM_PRIMS = frozenset({
     "psum", "pmin", "pmax", "all_gather", "all_to_all", "ppermute",
     "psum_scatter", "pbroadcast", "reduce_scatter",
-    # shard_map's check_rep rewrite renames psum to psum2 — same verb on
-    # the wire; the census normalizes it back to "psum"
-    "psum2",
 })
-_PRIM_ALIASES = {"psum2": "psum"}
 # Primitives whose bodies execute once per carried iteration: a collective
 # inside one would turn the per-stream assembly psum into per-window traffic.
 _LOOP_PRIMS = frozenset({"scan", "while"})
@@ -198,7 +191,7 @@ def collective_census(closed, in_loop_only: bool = False) -> dict[str, int]:
             continue
         name = eqn.primitive.name
         if name in COMM_PRIMS or name == "axis_index":
-            census[_PRIM_ALIASES.get(name, name)] += 1
+            census[name] += 1
     return dict(census)
 
 

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.attention import NEG_INF, decode_attention
@@ -57,6 +56,6 @@ def decode_attention_spmd(mesh, q, k_cache, v_cache, length, *,
 
     rep = P(None, None, None, None)
     kv = P(None, seq_axis, None, None)
-    fn = shard_map(local, mesh=mesh, in_specs=(rep, kv, kv, P()),
-                   out_specs=rep, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(rep, kv, kv, P()),
+                       out_specs=rep, check_vma=False)
     return fn(q, k_cache, v_cache, length)

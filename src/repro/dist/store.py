@@ -29,7 +29,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import engine
@@ -249,10 +248,10 @@ def _sharded_fn(cfg: EngineConfig, mesh, axis: str):
         return (st2, cr2, _psum_results(res, axis),
                 jax.tree.map(lambda x: jax.lax.psum(x, axis), io))
 
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(st_spec, P(), P(), P()),
-                   out_specs=(st_spec, P(), P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(st_spec, P(), P(), P()),
+                       out_specs=(st_spec, P(), P(), P()),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -309,10 +308,10 @@ def _sharded_stream_fn(cfg: EngineConfig, mesh, axis: str,
         return res_io + (outs[2],) if traced else res_io
 
     out_specs = (st_spec, P(), P(), P()) + ((P(),) if traced else ())
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(st_spec, P(), P(), P()),
-                   out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(st_spec, P(), P(), P()),
+                       out_specs=out_specs,
+                       check_vma=False)
     return jax.jit(fn, donate_argnums=(0, 1))
 
 

@@ -1,10 +1,10 @@
 """Jit'd wrapper for scan_probe.
 
 DESIGN.md §10.3 (fused SCAN reader-probe pass): public wrapper with the
-same padded-tail handling as wc_combine — non-block-multiple N is padded
-with (+inf key, setcode -1, no writer, absent) lanes, which open or extend
-a trailing sentinel run *after* every real lane; both outputs are prefix
-sweeps, so slicing back to N needs no fix-up.
+same padded tile layout as wc_combine — any N is padded with (+inf key,
+setcode -1, no writer, absent) lanes, which open or extend a trailing
+sentinel run *after* every real lane; both outputs are prefix sweeps, so
+slicing back to N needs no fix-up.
 """
 from __future__ import annotations
 
@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.kernels.scan_probe.ref import scan_probe_ref
 from repro.kernels.scan_probe.scan_probe import scan_probe
+from repro.kernels.tile_scan import layout, to_tiles
 
 __all__ = ["scan_probe_op", "scan_probe_ref"]
 
@@ -20,26 +21,15 @@ _BIG = 2**31 - 1   # python int: this module may first be imported inside a jit 
 
 
 def scan_probe_op(keys_sorted, setcode, writer, e_init,
-                  block=1024, interpret=None):
-    if keys_sorted.dtype != jnp.int32:
-        keys_sorted = keys_sorted.astype(jnp.int32)
+                  block=4096, interpret=None):
+    """``(e_before bool, waits int32)`` per sorted lane — the contract of
+    ``scan_probe_ref``."""
     n = keys_sorted.shape[0]
-    block = min(block, n)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    pad = (-n) % block
-    setcode = setcode.astype(jnp.int32)
-    writer_i = writer.astype(jnp.int32)
-    einit_i = e_init.astype(jnp.int32)
-    if pad:
-        zi = jnp.zeros((pad,), jnp.int32)
-        keys_sorted = jnp.concatenate(
-            [keys_sorted, jnp.full((pad,), _BIG, jnp.int32)])
-        setcode = jnp.concatenate([setcode, zi - 1])
-        writer_i = jnp.concatenate([writer_i, zi])
-        einit_i = jnp.concatenate([einit_i, zi])
-    e_before, waits = scan_probe(keys_sorted, setcode, writer_i, einit_i,
-                                 block=block, interpret=interpret)
-    if pad:
-        e_before, waits = e_before[:n], waits[:n]
-    return e_before, waits
+    lanes, rows, n_rows = layout(n, block, interpret)
+    planes = [to_tiles(x.astype(jnp.int32), fill, lanes, n_rows)
+              for x, fill in ((keys_sorted, _BIG), (setcode, -1),
+                              (writer, 0), (e_init, 0))]
+    e_before, waits = scan_probe(*planes, rows=rows, interpret=interpret)
+    return e_before.reshape(-1)[:n] != 0, waits.reshape(-1)[:n]

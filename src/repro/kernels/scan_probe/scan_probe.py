@@ -4,11 +4,11 @@ by (key, pos) emitting, per lane, the existence bit observed just before it
 (``waits``) — the engine's step-5c probe resolution and ``reader_waits``
 rank in a single sweep (DESIGN.md §10.3), replacing two full sorts.
 
-Cross-block runs use the same sequential-grid carry idiom as wc_combine
-(DESIGN.md §2.1): TPU grid execution is ordered, so block i reads the SMEM
-carry block i-1 wrote.  The carry holds (previous block's last key, the
-last setcode seen in its still-open run [-1 if none], the writer count so
-far in that run).
+Layout and cross-block carry follow wc_combine (DESIGN.md §2.1): the lanes
+are swept as tile-aligned ``(rows, lanes)`` int32 blocks on a sequential
+grid, and block i reads the SMEM carry block i-1 wrote.  The carry holds
+(previous block's last key, the last setcode seen in its still-open run
+[-1 if none], the writer count so far in that run).
 """
 from __future__ import annotations
 
@@ -19,76 +19,70 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_NEG = -2**31 + 1              # python int: jnp constants would be captured
+from repro.kernels.tile_scan import NEG, cumulative, flat_index, shift1, tail
 
 
 def _kernel(keys_ref, set_ref, writer_ref, einit_ref,
-            eb_ref, waits_ref, carry_ref, *, block: int):
-    bi = pl.program_id(0)
-
-    @pl.when(bi == 0)
+            eb_ref, waits_ref, carry_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        carry_ref[0] = jnp.int32(_NEG)   # "no previous key"
+        carry_ref[0] = jnp.int32(NEG)    # "no previous key"
         carry_ref[1] = jnp.int32(-1)     # open run has no setter yet
         carry_ref[2] = jnp.int32(0)      # writers so far in open run
 
-    k = keys_ref[...]                    # (block,) int32
-    sc = set_ref[...]                    # (block,) int32 in {-1, 0, 1}
-    w = writer_ref[...]                  # (block,) int32 in {0, 1}
-    ei = einit_ref[...]                  # (block,) int32 in {0, 1}
-    prev_key = carry_ref[0]
+    k = keys_ref[...]                    # (rows, lanes) int32
+    sc = set_ref[...]                    # int32 in {-1, 0, 1}
+    w = writer_ref[...]                  # int32 in {0, 1}
+    ei = einit_ref[...]                  # int32 in {0, 1}
     carry_set = carry_ref[1]
     carry_w = carry_ref[2]
-    idx = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)[:, 0]
-    kprev = jnp.where(idx == 0, prev_key, jnp.roll(k, 1))
-    first = k != kprev
-    start = jax.lax.cummax(jnp.where(first, idx, jnp.int32(_NEG)))
-    in_carry = start == _NEG             # run continues from previous block
+    row, lane, idx = flat_index(k.shape)
+    first = k != shift1(k, carry_ref[0], row, lane)
+    start = cumulative(jnp.where(first, idx, NEG), jnp.maximum, NEG,
+                       row, lane)
+    in_carry = start == NEG              # run continues from previous block
     start_c = jnp.where(in_carry, 0, start)
-    # last setter strictly before me, within this block and run
+    # last setter at or before me (g), strictly before me (g_excl), in-run
     enc = jnp.where(sc >= 0, 2 * idx + sc, -1)
-    g = jax.lax.cummax(enc)
-    g_excl = jnp.where(idx == 0, jnp.int32(-1), jnp.roll(g, 1))
+    g = cumulative(enc, jnp.maximum, -1, row, lane)
+    g_excl = shift1(g, -1, row, lane)
     has = (g_excl >= 0) & ((g_excl >> 1) >= start_c)
-    e_b = jnp.where(has, (g_excl & 1) == 1,
-                    jnp.where(in_carry & (carry_set >= 0),
-                              carry_set == 1, ei == 1))
+    # no in-run setter yet: the carried run's setter, else the initial bit
+    # (int32 throughout: Mosaic cannot broadcast a scalar bool)
+    run_set_in = jnp.where(in_carry, carry_set, -1)
+    e_b = jnp.where(has, g_excl & 1,
+                    jnp.where(run_set_in >= 0, run_set_in, ei))
     # writers strictly ahead of me in my run
-    cw = jnp.cumsum(w)
-    cex = cw - w
-    base = jax.lax.cummax(jnp.where(first, cex, 0))
+    cex = cumulative(w, jnp.add, 0, row, lane) - w
+    base = cumulative(jnp.where(first, cex, 0), jnp.maximum, 0, row, lane)
     waits = cex - jnp.where(in_carry, 0, base) + jnp.where(in_carry, carry_w, 0)
     eb_ref[...] = e_b
     waits_ref[...] = waits
     # carry out: tail lane's key + its run's last setcode and writer count
-    t = block - 1
-    g_inc = g[t]
-    has_t = (g_inc >= 0) & ((g_inc >> 1) >= start_c[t])
-    carry_ref[0] = k[t]
-    carry_ref[1] = jnp.where(has_t, g_inc & 1,
-                             jnp.where(in_carry[t], carry_set, jnp.int32(-1)))
-    carry_ref[2] = waits[t] + w[t]
+    has_inc = (g >= 0) & ((g >> 1) >= start_c)
+    run_set = jnp.where(has_inc, g & 1, run_set_in)
+    carry_ref[0] = tail(k, idx)
+    carry_ref[1] = tail(run_set, idx)
+    carry_ref[2] = tail(waits + w, idx)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def scan_probe(keys_sorted, setcode, writer, e_init, *,
-               block=1024, interpret=False):
-    """All inputs (N,) int32, N a multiple of ``block``, sorted by (key,
-    pos).  ``writer``/``e_init`` are 0/1 ints (bool loads are avoided in
-    the kernel body).  Returns ``(e_before bool, waits int32)``."""
-    n = keys_sorted.shape[0]
-    block = min(block, n)
-    n_blocks = n // block
-    kernel = functools.partial(_kernel, block=block)
-    spec = pl.BlockSpec((block,), lambda i: (i,))
-    e_before, waits = pl.pallas_call(
-        kernel,
-        grid=(n_blocks,),
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+def scan_probe(keys_sorted, setcode, writer, e_init, *, rows,
+               interpret=False):
+    """All inputs ``(R, lanes)`` int32 with ``R`` a multiple of ``rows``
+    (the block height), sorted by (key, pos) in row-major order;
+    ``writer``/``e_init`` are 0/1.  Returns int32 ``(e_before, waits)``."""
+    n_rows, lanes = keys_sorted.shape
+    spec = pl.BlockSpec((rows, lanes), lambda i: (i, 0))
+    out = jax.ShapeDtypeStruct(keys_sorted.shape, jnp.int32)
+    return pl.pallas_call(
+        _kernel,
+        grid=(n_rows // rows,),
         in_specs=[spec, spec, spec, spec],
         out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((n,), jnp.bool_),
-                   jax.ShapeDtypeStruct((n,), jnp.int32)],
+        out_shape=[out, out],
         scratch_shapes=[pltpu.SMEM((3,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(keys_sorted, setcode, writer, e_init)
-    return e_before, waits

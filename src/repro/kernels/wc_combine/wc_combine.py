@@ -1,13 +1,14 @@
 """Global write-combining Pallas kernel: one VMEM pass over a SORTED key run
-emitting, per element, (is_first, is_last, rank) — the materialized wait
-queues of §4.2 (detect + combine in one sweep).
+emitting, per element, its rank within its run — the materialized wait
+queues of §4.2 (detect + combine in one sweep).  ``rank == 0`` marks a run
+head, and a run tail is the element before the next head, so the rank
+plane alone carries ``(is_first, is_last, rank)``.
 
-Cross-block runs are handled by a sequential grid with a carry scratch
-(previous block's last key + its accumulated run length): TPU grid execution
-is ordered, so block i reads the carry block i-1 wrote.
-
-Used by: the dataplane engine (combine path), the MoE dispatch
-(rank-within-expert), and the embedding-gradient combiner.
+The ``(N,)`` keys are viewed as an ``(N // lanes, lanes)`` int32 array and
+swept in tile-aligned ``(rows, lanes)`` blocks (``kernels/tile_scan.py``).
+Cross-block runs are handled by a sequential grid with an SMEM carry
+(previous block's last key + its accumulated run length): TPU grid
+execution is ordered, so block i reads the carry block i-1 wrote.
 
 DESIGN.md §2.1 (the combine primitive): Pallas twin of
 core/combine.plan_combine — identical contract, fused VMEM pass.
@@ -21,64 +22,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tile_scan import NEG, cumulative, flat_index, shift1, tail
 
-def _kernel(keys_ref, first_ref, last_ref, rank_ref, carry_ref, *,
-            block: int, n_blocks: int):
-    bi = pl.program_id(0)
 
-    @pl.when(bi == 0)
+def _kernel(keys_ref, rank_ref, carry_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        carry_ref[0] = jnp.int32(-2**31 + 1)   # "no previous key"
+        carry_ref[0] = jnp.int32(NEG)          # "no previous key"
         carry_ref[1] = jnp.int32(0)            # run length so far
 
-    k = keys_ref[...]                          # (block,)
-    prev_key = carry_ref[0]
+    k = keys_ref[...]                          # (rows, lanes) int32
     prev_len = carry_ref[1]
-    idx = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)[:, 0]
-    kprev = jnp.where(idx == 0, prev_key, jnp.roll(k, 1))
-    first = k != kprev
-    # rank within run: idx - start_of_run (+ carry for a continued first run)
-    start = jax.lax.cummax(jnp.where(first, idx, jnp.int32(-2**31 + 1)))
-    in_carry_run = start == (-2**31 + 1)       # run continues from prev block
-    rank = jnp.where(in_carry_run, idx + prev_len, idx - start)
-    # is_last: next element differs (last block: trailing element is last)
-    knext = jnp.where(idx == block - 1, jnp.int32(-2**31 + 2), jnp.roll(k, -1))
-    last = k != knext
-    first_ref[...] = first
-    last_ref[...] = last
+    row, lane, idx = flat_index(k.shape)
+    first = k != shift1(k, carry_ref[0], row, lane)
+    # rank within run: idx - start_of_run; a run continued from the previous
+    # block "starts" prev_len lanes before this block
+    start = cumulative(jnp.where(first, idx, -prev_len), jnp.maximum, NEG,
+                       row, lane)
+    rank = idx - start
     rank_ref[...] = rank
     # carry out: last key + length of its (possibly continued) run
-    tail_rank = rank[block - 1] + 1
-    carry_ref[0] = k[block - 1]
-    carry_ref[1] = tail_rank
+    carry_ref[0] = tail(k, idx)
+    carry_ref[1] = tail(rank, idx) + 1
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def wc_combine(keys_sorted, *, block=1024, interpret=False):
-    """keys_sorted: (N,) int32 ascending.  Returns (is_first, is_last, rank).
-    The final element of block i and first of block i+1 are stitched via the
-    sequential carry, so ``is_last``/``rank`` are globally correct except
-    that is_last at a block boundary is resolved by the NEXT block's
-    is_first — callers get exact semantics via the returned pair:
-    element i is a true run tail iff is_last[i] and (i == N-1 or
-    is_first[i+1]); the wrapper fixes this up (cheap elementwise pass)."""
-    n = keys_sorted.shape[0]
-    block = min(block, n)
-    n_blocks = n // block
-    kernel = functools.partial(_kernel, block=block, n_blocks=n_blocks)
-    first, last, rank = pl.pallas_call(
-        kernel,
-        grid=(n_blocks,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                   pl.BlockSpec((block,), lambda i: (i,)),
-                   pl.BlockSpec((block,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((n,), jnp.bool_),
-                   jax.ShapeDtypeStruct((n,), jnp.bool_),
-                   jax.ShapeDtypeStruct((n,), jnp.int32)],
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+def wc_combine(keys_sorted, *, rows, interpret=False):
+    """keys_sorted: ``(R, lanes)`` int32, ascending in row-major order, with
+    ``R`` a multiple of ``rows`` (the block height).  Returns the in-run
+    rank plane, same shape."""
+    n_rows, lanes = keys_sorted.shape
+    spec = pl.BlockSpec((rows, lanes), lambda i: (i, 0))
+    return pl.pallas_call(
+        _kernel,
+        grid=(n_rows // rows,),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(keys_sorted.shape, jnp.int32),
         scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(keys_sorted)
-    # stitch block boundaries: i is a tail iff the next element starts a run
-    nxt_first = jnp.concatenate([first[1:], jnp.ones((1,), bool)])
-    return first, last & nxt_first, rank

@@ -29,12 +29,10 @@ __all__ = ["HLOCost", "parse_hlo", "cost_dict"]
 
 
 def cost_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` to a dict: jax<=0.4.x returns
-    a list with one entry per executable module."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()`` as a dict (empty when the backend gives
+    none)."""
+    return compiled.cost_analysis() or {}
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

@@ -138,7 +138,7 @@ def test_jaxpr_audit_flags_injected_f64():
     def leaky(x):
         return x.astype("float64") * 2.0
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(leaky)(jnp.ones((4,), jnp.float32))
         viols = jaxpr_check.audit_graph(closed, "toy")
     assert any("float64" in v.message for v in viols)
@@ -153,7 +153,6 @@ def test_jaxpr_audit_clean_on_allowed_dtypes():
 
 
 def test_jaxpr_census_counts_injected_extra_psum():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     if jax.device_count() < 2:
@@ -168,8 +167,8 @@ def test_jaxpr_census_counts_injected_extra_psum():
 
     arg = jnp.ones((2, 4), jnp.float32)
     for fn, want in ((one_psum, 1), (two_psums, 2)):
-        sharded = shard_map(fn, mesh=mesh, in_specs=P("data"),
-                            out_specs=P())
+        sharded = jax.shard_map(fn, mesh=mesh, in_specs=P("data"),
+                                out_specs=P(), check_vma=False)
         census = jaxpr_check.collective_census(jax.make_jaxpr(sharded)(arg))
         assert census.get("psum", 0) == want
     # the contract comparison is exact: an extra collective is a mismatch
