@@ -1,0 +1,10 @@
+"""Device time per window of the engine's step 1, linearization: the validity
+masks, the sorted last-setter sweep and ``wc_combine``: the device time of
+the ops in its ``engine.s*`` scopes of the call program
+(``bench/program_trace.py``), mean over devices."""
+
+from bench import program_trace
+
+
+def read(ctx):
+    return program_trace.step_ms_per_window(ctx, "engine.linearize_ms_per_window")

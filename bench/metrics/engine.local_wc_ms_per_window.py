@@ -1,0 +1,9 @@
+"""Device time per window of the engine's step 4, local write combining over
+the (key, CN) cell table: the device time of the ops in its ``engine.s*``
+scopes of the call program (``bench/program_trace.py``), mean over devices."""
+
+from bench import program_trace
+
+
+def read(ctx):
+    return program_trace.step_ms_per_window(ctx, "engine.local_wc_ms_per_window")

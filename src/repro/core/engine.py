@@ -264,113 +264,116 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
     ``Results.orphan_wait``); locks on slots with no surviving waiter stay
     recorded in ``StoreState.stranded`` until their next locker arrives.
     """
-    b = batch.kinds.shape[0]
-    kinds, keys, values, pos, cn = (batch.kinds, batch.keys, batch.values,
-                                    batch.pos, batch.cn)
-    if valid is None:
-        valid = kinds != OpKind.NOP
-    else:
-        valid = valid & (kinds != OpKind.NOP)
-    if cfg.scan_max == 0:
-        # no probe capacity compiled in: SCAN lanes must not silently charge
-        # point-op I/O and return 0 rows — they are dropped here, and the
-        # point-op stores reject them loudly before ever reaching the engine
-        valid = valid & (kinds != OpKind.SCAN)
-    # present: ops issued into this window (including ones whose CN crashes
-    # mid-window — the orphan candidates); valid: ops that complete.
-    present = valid
-    if alive is not None:
-        a = jnp.asarray(alive, bool)
-        valid = valid & a[jnp.clip(cn, 0, a.shape[0] - 1)]
-    # valid: ops present in the window (credit plane); valid_o: ops whose
-    # store state this shard owns (data plane).  Identical when not sharded.
-    valid_o = valid if owned is None else valid & owned
-    base = jnp.int32(0) if slot_base is None else jnp.asarray(slot_base, jnp.int32)
-    is_search = (kinds == OpKind.SEARCH) & valid_o
-    is_insert = (kinds == OpKind.INSERT) & valid_o
-    is_update = (kinds == OpKind.UPDATE) & valid_o
-    is_delete = (kinds == OpKind.DELETE) & valid_o
-    upd_full = (kinds == OpKind.UPDATE) & valid
+    with jax.named_scope("engine.s1_linearize"):
+        b = batch.kinds.shape[0]
+        kinds, keys, values, pos, cn = (batch.kinds, batch.keys, batch.values,
+                                        batch.pos, batch.cn)
+        if valid is None:
+            valid = kinds != OpKind.NOP
+        else:
+            valid = valid & (kinds != OpKind.NOP)
+        if cfg.scan_max == 0:
+            # no probe capacity compiled in: SCAN lanes must not silently charge
+            # point-op I/O and return 0 rows — they are dropped here, and the
+            # point-op stores reject them loudly before ever reaching the engine
+            valid = valid & (kinds != OpKind.SCAN)
+        # present: ops issued into this window (including ones whose CN crashes
+        # mid-window — the orphan candidates); valid: ops that complete.
+        present = valid
+        if alive is not None:
+            a = jnp.asarray(alive, bool)
+            valid = valid & a[jnp.clip(cn, 0, a.shape[0] - 1)]
+        # valid: ops present in the window (credit plane); valid_o: ops whose
+        # store state this shard owns (data plane).  Identical when not sharded.
+        valid_o = valid if owned is None else valid & owned
+        base = jnp.int32(0) if slot_base is None else jnp.asarray(slot_base, jnp.int32)
+        is_search = (kinds == OpKind.SEARCH) & valid_o
+        is_insert = (kinds == OpKind.INSERT) & valid_o
+        is_update = (kinds == OpKind.UPDATE) & valid_o
+        is_delete = (kinds == OpKind.DELETE) & valid_o
+        upd_full = (kinds == OpKind.UPDATE) & valid
 
-    # ---- 1. linearize: one sorted last-setter sweep serializes every
-    # slot's queue (DESIGN.md §10.2) ----
-    plan_all = wc.plan_combine(keys, pos, valid_o, backend=cfg.kernel_backend)
-    perm = plan_all.perm
-    idx = jnp.arange(b, dtype=jnp.int32)
-    seg_start = idx - plan_all.rank
-    seg_end = seg_start + plan_all.run_length - 1
-    ks = kinds[perm]
-    vals = values[perm]
-    v_sorted = valid_o[perm]
-    ins_s = v_sorted & (ks == OpKind.INSERT)
-    upd_s = v_sorted & (ks == OpKind.UPDATE)
-    del_s = v_sorted & (ks == OpKind.DELETE)
-    # incoming (pre-window) state per sorted element's slot (shard-local)
-    slot = jnp.clip(keys[perm] - base, 0, cfg.n_slots - 1)
-    p = state.ptr[slot]
-    e_init = p != NULL_PTR
-    v_init = jnp.where(e_init, state.heap[jnp.clip(p, 0)], _NONE)
-    # existence BEFORE each op: the last INSERT (sets present) or DELETE
-    # (sets absent) strictly before it in its run; else the slot's
-    # pre-window bit.  UPDATE/SEARCH never flip existence.
-    setcode = jnp.where(ins_s, jnp.int32(1),
-                        jnp.where(del_s, jnp.int32(0), jnp.int32(-1)))
-    g_excl = _last_before(setcode >= 0, 2 * idx + setcode)
-    has = (g_excl >= 0) & ((g_excl >> 1) >= seg_start)
-    e_before = jnp.where(has, (g_excl & 1) == 1, e_init)
-    # value BEFORE each op: the last value-writing event strictly before it
-    # (INSERT into empty / UPDATE of occupied write the payload, successful
-    # DELETE writes the tombstone); else the slot's pre-window value.
-    w_ev = (ins_s & ~e_before) | ((upd_s | del_s) & e_before)
-    val_w = jnp.where(del_s, _NONE, vals)
-    gv_excl = _last_before(w_ev, idx)
-    hasv = (gv_excl >= 0) & (gv_excl >= seg_start)
-    v_before = jnp.where(hasv, val_w[jnp.clip(gv_excl, 0)], v_init)
-    # per-op success / search results (sorted order)
-    ok_s = jnp.where(ks == OpKind.SEARCH, e_before,
-            jnp.where(ks == OpKind.INSERT, ~e_before,
-             jnp.where((ks == OpKind.UPDATE) | (ks == OpKind.DELETE), e_before, False)))
-    ok_s = ok_s & v_sorted
-    val_s = jnp.where((ks == OpKind.SEARCH) & e_before & v_sorted,
-                      v_before, _NONE)
-    # state AFTER each op (at run tails: the new slot contents)
-    e_fin = jnp.where(setcode >= 0, setcode == 1, e_before)
-    v_fin = jnp.where(w_ev, val_w, v_before)
-    seg_changed = ok_s & (ks != OpKind.SEARCH)          # any successful IDU
-    # per-run reductions via prefix sums gathered at run bounds
-    sc_i = seg_changed.astype(jnp.int32)
-    cw = jnp.cumsum(sc_i)
-    seg_any_write = (cw[seg_end] - (cw - sc_i)[seg_start]) > 0
+        # ---- 1. linearize: one sorted last-setter sweep serializes every
+        # slot's queue (DESIGN.md §10.2) ----
+        plan_all = wc.plan_combine(keys, pos, valid_o, backend=cfg.kernel_backend)
+        perm = plan_all.perm
+        idx = jnp.arange(b, dtype=jnp.int32)
+        seg_start = idx - plan_all.rank
+        seg_end = seg_start + plan_all.run_length - 1
+        ks = kinds[perm]
+        vals = values[perm]
+        v_sorted = valid_o[perm]
+        ins_s = v_sorted & (ks == OpKind.INSERT)
+        upd_s = v_sorted & (ks == OpKind.UPDATE)
+        del_s = v_sorted & (ks == OpKind.DELETE)
+        # incoming (pre-window) state per sorted element's slot (shard-local)
+        slot = jnp.clip(keys[perm] - base, 0, cfg.n_slots - 1)
+        p = state.ptr[slot]
+        e_init = p != NULL_PTR
+        v_init = jnp.where(e_init, state.heap[jnp.clip(p, 0)], _NONE)
+        # existence BEFORE each op: the last INSERT (sets present) or DELETE
+        # (sets absent) strictly before it in its run; else the slot's
+        # pre-window bit.  UPDATE/SEARCH never flip existence.
+        setcode = jnp.where(ins_s, jnp.int32(1),
+                            jnp.where(del_s, jnp.int32(0), jnp.int32(-1)))
+        g_excl = _last_before(setcode >= 0, 2 * idx + setcode)
+        has = (g_excl >= 0) & ((g_excl >> 1) >= seg_start)
+        e_before = jnp.where(has, (g_excl & 1) == 1, e_init)
+        # value BEFORE each op: the last value-writing event strictly before it
+        # (INSERT into empty / UPDATE of occupied write the payload, successful
+        # DELETE writes the tombstone); else the slot's pre-window value.
+        w_ev = (ins_s & ~e_before) | ((upd_s | del_s) & e_before)
+        val_w = jnp.where(del_s, _NONE, vals)
+        gv_excl = _last_before(w_ev, idx)
+        hasv = (gv_excl >= 0) & (gv_excl >= seg_start)
+        v_before = jnp.where(hasv, val_w[jnp.clip(gv_excl, 0)], v_init)
+        # per-op success / search results (sorted order)
+        ok_s = jnp.where(ks == OpKind.SEARCH, e_before,
+                jnp.where(ks == OpKind.INSERT, ~e_before,
+                 jnp.where((ks == OpKind.UPDATE) | (ks == OpKind.DELETE), e_before, False)))
+        ok_s = ok_s & v_sorted
+        val_s = jnp.where((ks == OpKind.SEARCH) & e_before & v_sorted,
+                          v_before, _NONE)
+        # state AFTER each op (at run tails: the new slot contents)
+        e_fin = jnp.where(setcode >= 0, setcode == 1, e_before)
+        v_fin = jnp.where(w_ev, val_w, v_before)
+        seg_changed = ok_s & (ks != OpKind.SEARCH)          # any successful IDU
+        # per-run reductions via prefix sums gathered at run bounds
+        sc_i = seg_changed.astype(jnp.int32)
+        cw = jnp.cumsum(sc_i)
+        seg_any_write = (cw[seg_end] - (cw - sc_i)[seg_start]) > 0
     # ---- 2. commit final slot states (one out-of-place write per queue) ----
     # Out-of-bounds indices with mode="drop" mask out non-committing lanes.
-    tail = plan_all.is_last & seg_any_write & v_sorted
-    oob_h, oob_s = jnp.int32(cfg.heap_slots), jnp.int32(cfg.n_slots)
-    n_commits = jnp.sum(tail.astype(jnp.int32))
-    commit_rank = jnp.cumsum(tail.astype(jnp.int32)) - 1
-    loc = (state.heap_top + commit_rank).astype(jnp.int32)
-    heap = state.heap.at[jnp.where(tail, loc, oob_h)].set(v_fin, mode="drop")
-    new_ptr_val = jnp.where(e_fin, loc, NULL_PTR)
-    ptr = state.ptr.at[jnp.where(tail, slot, oob_s)].set(new_ptr_val, mode="drop")
-    # version: +1 per successful DELETE (mod 16 — the 4-bit field of Fig 8)
-    del_succ = del_s & ok_s
-    ds_i = del_succ.astype(jnp.int32)
-    cd = jnp.cumsum(ds_i)
-    run_del = cd[seg_end] - (cd - ds_i)[seg_start]
-    ver = (state.ver.at[jnp.where(plan_all.is_last, slot, oob_s)]
-           .add(run_del, mode="drop")) % 16
+    with jax.named_scope("engine.s2_commit"):
+        tail = plan_all.is_last & seg_any_write & v_sorted
+        oob_h, oob_s = jnp.int32(cfg.heap_slots), jnp.int32(cfg.n_slots)
+        n_commits = jnp.sum(tail.astype(jnp.int32))
+        commit_rank = jnp.cumsum(tail.astype(jnp.int32)) - 1
+        loc = (state.heap_top + commit_rank).astype(jnp.int32)
+        heap = state.heap.at[jnp.where(tail, loc, oob_h)].set(v_fin, mode="drop")
+        new_ptr_val = jnp.where(e_fin, loc, NULL_PTR)
+        ptr = state.ptr.at[jnp.where(tail, slot, oob_s)].set(new_ptr_val, mode="drop")
+        # version: +1 per successful DELETE (mod 16 — the 4-bit field of Fig 8)
+        del_succ = del_s & ok_s
+        ds_i = del_succ.astype(jnp.int32)
+        cd = jnp.cumsum(ds_i)
+        run_del = cd[seg_end] - (cd - ds_i)[seg_start]
+        ver = (state.ver.at[jnp.where(plan_all.is_last, slot, oob_s)]
+               .add(run_del, mode="drop")) % 16
 
     # ---- 3. synchronization-mode decision (CIDER credit split, §4.3) ----
     # Decided on the FULL window (upd_full, global keys): every shard's
     # replica of the credit table sees every op and stays bit-identical.
-    if cfg.mode == SyncMode.CIDER:
-        credits2, pess_full = credit_decide(credits, keys, upd_full,
-                                            credits.credit.shape[0])
-    elif cfg.mode in (SyncMode.MCS, SyncMode.SPIN):
-        credits2, pess_full = credits, upd_full
-    else:  # OSYNC
-        credits2, pess_full = credits, jnp.zeros_like(upd_full)
-    pess = pess_full & valid_o
-    opt_upd = is_update & ~pess_full
+    with jax.named_scope("engine.s3_credit"):
+        if cfg.mode == SyncMode.CIDER:
+            credits2, pess_full = credit_decide(credits, keys, upd_full,
+                                                credits.credit.shape[0])
+        elif cfg.mode in (SyncMode.MCS, SyncMode.SPIN):
+            credits2, pess_full = credits, upd_full
+        else:  # OSYNC
+            credits2, pess_full = credits, jnp.zeros_like(upd_full)
+        pess = pess_full & valid_o
+        opt_upd = is_update & ~pess_full
 
     # ---- 4. effective writers after local WC (per (key, CN) group) --------
     # Local WC combines same-CN UPDATEs (applied to every baseline, §5.1);
@@ -384,131 +387,133 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
     # window serves every subset — both executor masks are subsets of
     # valid_o, so group_last on the shared plan matches a dedicated
     # local_executors sort per mask.
-    n_cns_static = None
-    for liveness in (alive, died):
-        if liveness is not None:
-            n_cns_static = jnp.asarray(liveness).shape[0]
-            break
-    if n_cns_static is not None:
-        def _group_last(mask):
-            return wc.local_executors_scatter(keys, cn, pos, mask,
-                                              cfg.n_slots, n_cns_static, base)
-    else:
-        gplan = wc.plan_groups(keys, cn, pos, valid_o) if cfg.local_wc else None
+    with jax.named_scope("engine.s4_local_wc"):
+        n_cns_static = None
+        for liveness in (alive, died):
+            if liveness is not None:
+                n_cns_static = jnp.asarray(liveness).shape[0]
+                break
+        if n_cns_static is not None:
+            def _group_last(mask):
+                return wc.local_executors_scatter(keys, cn, pos, mask,
+                                                  cfg.n_slots, n_cns_static, base)
+        else:
+            gplan = wc.plan_groups(keys, cn, pos, valid_o) if cfg.local_wc else None
 
-        def _group_last(mask):
-            return wc.group_last(gplan, mask)
-    loc_exec_opt = _group_last(opt_upd) if cfg.local_wc else opt_upd
-    if cfg.mode in (SyncMode.CIDER, SyncMode.OSYNC) or not cfg.local_wc:
-        # CIDER: global WC subsumes local; OSYNC: pess is statically empty
-        loc_exec_pess = pess
-    else:
-        loc_exec_pess = _group_last(pess)
+            def _group_last(mask):
+                return wc.group_last(gplan, mask)
+        loc_exec_opt = _group_last(opt_upd) if cfg.local_wc else opt_upd
+        if cfg.mode in (SyncMode.CIDER, SyncMode.OSYNC) or not cfg.local_wc:
+            # CIDER: global WC subsumes local; OSYNC: pess is statically empty
+            loc_exec_pess = pess
+        else:
+            loc_exec_pess = _group_last(pess)
 
     # ---- 5. per-mode I/O metering ------------------------------------------
-    i64 = jnp.int32
-    def s(x):
-        return jnp.sum(x.astype(i64))
+    with jax.named_scope("engine.s5_meter"):
+        i64 = jnp.int32
+        def s(x):
+            return jnp.sum(x.astype(i64))
 
-    n_found_search = jnp.sum(((ks == OpKind.SEARCH) & ok_s).astype(jnp.int32))
-    reads = s(valid_o) * cfg.index_read_iops + n_found_search
-    mn_bytes = (s(valid_o) * cfg.index_read_bytes + n_found_search * cfg.value_bytes)
-    # read-only bytes bill to ONE replica under replication (DESIGN.md §13);
-    # every other mn_bytes contribution below is write-class and fans out xR
-    ro_bytes = mn_bytes
-    writes = jnp.zeros((), i64)
-    cas = jnp.zeros((), i64)
-    faa = jnp.zeros((), i64)
-    cn_msgs = jnp.zeros((), i64)
-    retries_total = jnp.zeros((), i64)
-    combined_total = jnp.zeros((), i64)
-    per_op_retries = jnp.zeros((b,), jnp.int32)
-    per_op_combined = jnp.zeros((b,), bool)
-    per_op_batch = jnp.ones((b,), jnp.int32)
-    per_op_rank = jnp.zeros((b,), jnp.int32)
-    per_op_rows = jnp.zeros((b,), jnp.int32)
+        n_found_search = jnp.sum(((ks == OpKind.SEARCH) & ok_s).astype(jnp.int32))
+        reads = s(valid_o) * cfg.index_read_iops + n_found_search
+        mn_bytes = (s(valid_o) * cfg.index_read_bytes + n_found_search * cfg.value_bytes)
+        # read-only bytes bill to ONE replica under replication (DESIGN.md §13);
+        # every other mn_bytes contribution below is write-class and fans out xR
+        ro_bytes = mn_bytes
+        writes = jnp.zeros((), i64)
+        cas = jnp.zeros((), i64)
+        faa = jnp.zeros((), i64)
+        cn_msgs = jnp.zeros((), i64)
+        retries_total = jnp.zeros((), i64)
+        combined_total = jnp.zeros((), i64)
+        per_op_retries = jnp.zeros((b,), jnp.int32)
+        per_op_combined = jnp.zeros((b,), bool)
+        per_op_batch = jnp.ones((b,), jnp.int32)
+        per_op_rank = jnp.zeros((b,), jnp.int32)
+        per_op_rows = jnp.zeros((b,), jnp.int32)
 
-    # INSERTs: optimistic CAS on the empty pointer in every mode (§4.2.2);
-    # concurrent same-key INSERTs: exactly one wins, losers fail once.
-    writes += s(is_insert)
-    cas += s(is_insert)
-    mn_bytes += s(is_insert) * (cfg.value_bytes + cfg.ptr_bytes)
+        # INSERTs: optimistic CAS on the empty pointer in every mode (§4.2.2);
+        # concurrent same-key INSERTs: exactly one wins, losers fail once.
+        writes += s(is_insert)
+        cas += s(is_insert)
+        mn_bytes += s(is_insert) * (cfg.value_bytes + cfg.ptr_bytes)
 
-    # DELETEs: pessimistic modes lock (enqueue-CAS + ptr-CAS + epoch-FAA);
-    # under OSYNC they CAS-retry in the SAME per-key optimistic queue as
-    # concurrent UPDATEs (both CAS the same pointer), metered jointly below.
-    n_del = s(is_delete)
-    if cfg.mode != SyncMode.OSYNC:
-        cas += 2 * n_del
-        faa += n_del
-        mn_bytes += n_del * (2 * cfg.ptr_bytes + 8)
+        # DELETEs: pessimistic modes lock (enqueue-CAS + ptr-CAS + epoch-FAA);
+        # under OSYNC they CAS-retry in the SAME per-key optimistic queue as
+        # concurrent UPDATEs (both CAS the same pointer), metered jointly below.
+        n_del = s(is_delete)
+        if cfg.mode != SyncMode.OSYNC:
+            cas += 2 * n_del
+            faa += n_del
+            mn_bytes += n_del * (2 * cfg.ptr_bytes + 8)
 
-    # Optimistic CAS path -----------------------------------------------------
-    # One joint queue per key: UPDATE executors after local WC, plus (OSYNC
-    # only) DELETEs — cross-kind conflicts on a pointer retry against each
-    # other, so metering them as two independent queues undercounts retries.
-    if cfg.mode == SyncMode.OSYNC:
-        opt_queue = loc_exec_opt | is_delete
-    else:
-        opt_queue = loc_exec_opt
-    # opt_queue/loc_exec_pess ⊆ valid_o, so their queue statistics fall out
-    # of plan_all's existing sort (stats_from_plan, DESIGN.md §10.2) —
-    # bit-identical to per_key_stats, minus the extra lexsorts.
-    plan_o = wc.stats_from_plan(plan_all, opt_queue)
-    m_opt_writes = s(loc_exec_opt)                   # DELETEs write no heap
-    writes += m_opt_writes
-    cas += s(opt_queue) + plan_o.retry_sum
-    retries_total += plan_o.retry_sum
-    mn_bytes += (m_opt_writes * cfg.value_bytes
-                 + (s(opt_queue) + plan_o.retry_sum) * cfg.ptr_bytes)
-    combined_total += s(opt_upd) - m_opt_writes      # local-WC combined
-    per_op_retries = jnp.where(opt_queue, plan_o.rank_of, per_op_retries)
-    per_op_rank = jnp.where(opt_queue, plan_o.rank_of, per_op_rank)
-    per_op_combined = per_op_combined | (opt_upd & ~loc_exec_opt)
+        # Optimistic CAS path -----------------------------------------------------
+        # One joint queue per key: UPDATE executors after local WC, plus (OSYNC
+        # only) DELETEs — cross-kind conflicts on a pointer retry against each
+        # other, so metering them as two independent queues undercounts retries.
+        if cfg.mode == SyncMode.OSYNC:
+            opt_queue = loc_exec_opt | is_delete
+        else:
+            opt_queue = loc_exec_opt
+        # opt_queue/loc_exec_pess ⊆ valid_o, so their queue statistics fall out
+        # of plan_all's existing sort (stats_from_plan, DESIGN.md §10.2) —
+        # bit-identical to per_key_stats, minus the extra lexsorts.
+        plan_o = wc.stats_from_plan(plan_all, opt_queue)
+        m_opt_writes = s(loc_exec_opt)                   # DELETEs write no heap
+        writes += m_opt_writes
+        cas += s(opt_queue) + plan_o.retry_sum
+        retries_total += plan_o.retry_sum
+        mn_bytes += (m_opt_writes * cfg.value_bytes
+                     + (s(opt_queue) + plan_o.retry_sum) * cfg.ptr_bytes)
+        combined_total += s(opt_upd) - m_opt_writes      # local-WC combined
+        per_op_retries = jnp.where(opt_queue, plan_o.rank_of, per_op_retries)
+        per_op_rank = jnp.where(opt_queue, plan_o.rank_of, per_op_rank)
+        per_op_combined = per_op_combined | (opt_upd & ~loc_exec_opt)
 
-    # pessimistic subset
-    m_pe = s(loc_exec_pess)                          # effective queued writers
-    if cfg.mode == SyncMode.SPIN:
-        plan_p = wc.stats_from_plan(plan_all, loc_exec_pess)
-        polls = _backoff_polls(plan_p.rank_of * 3, cfg.backoff_cap)
-        polls_sum = s(jnp.where(loc_exec_pess, polls, 0))
-        writes += m_pe
-        cas += 3 * m_pe + polls_sum                  # lock + ptr + unlock + polls
-        retries_total += polls_sum
-        mn_bytes += m_pe * (cfg.value_bytes + 3 * cfg.ptr_bytes) + polls_sum * cfg.ptr_bytes
-        per_op_retries = jnp.where(loc_exec_pess, polls, per_op_retries)
-        per_op_rank = jnp.where(loc_exec_pess, plan_p.rank_of, per_op_rank)
-    elif cfg.mode == SyncMode.MCS:
-        writes += m_pe
-        cas += 2 * m_pe                              # enqueue masked-CAS + ptr CAS
-        faa += m_pe                                  # epoch release
-        plan_p = wc.stats_from_plan(plan_all, loc_exec_pess)
-        cn_msgs += 2 * s(jnp.where(loc_exec_pess, (plan_p.mult_of > 1), 0))
-        mn_bytes += m_pe * (cfg.value_bytes + 2 * cfg.ptr_bytes + 8)
-        per_op_batch = jnp.where(loc_exec_pess, 1, per_op_batch)
-        per_op_rank = jnp.where(loc_exec_pess, plan_p.rank_of, per_op_rank)
-    elif cfg.mode == SyncMode.CIDER:
-        # global WC: all queued writers on a key collapse to ONE executed write
-        plan_p = wc.stats_from_plan(plan_all, loc_exec_pess)
-        is_exec = loc_exec_pess & plan_p.is_tail     # queue tail = executor
-        n_q = s(is_exec)                             # number of wait queues
-        multi = loc_exec_pess & (plan_p.mult_of > 1)
-        n_multi_q = s(is_exec & (plan_p.mult_of > 1))
-        cas += m_pe + n_q                            # m enqueues + 1 ptr CAS per queue
-        writes += n_q                                # ONE combined write per queue
-        faa += m_pe                                  # every client's release FAA
-        reads += n_multi_q                           # coordinator tail lookup (step 1)
-        cn_msgs += s(jnp.where(is_exec & (plan_p.mult_of > 1),
-                               plan_p.mult_of + 1, 0))
-        mn_bytes += (m_pe * cfg.ptr_bytes + n_q * (cfg.value_bytes + cfg.ptr_bytes)
-                     + m_pe * 8 + n_multi_q * cfg.lock_bytes)
-        ro_bytes += n_multi_q * cfg.lock_bytes       # coordinator tail READ
-        combined_total += s(pess) - n_q
-        per_op_combined = per_op_combined | (pess & ~is_exec)
-        per_op_batch = jnp.where(loc_exec_pess, plan_p.mult_of, per_op_batch)
-        per_op_rank = jnp.where(loc_exec_pess, plan_p.rank_of, per_op_rank)
+        # pessimistic subset
+        m_pe = s(loc_exec_pess)                          # effective queued writers
+        if cfg.mode == SyncMode.SPIN:
+            plan_p = wc.stats_from_plan(plan_all, loc_exec_pess)
+            polls = _backoff_polls(plan_p.rank_of * 3, cfg.backoff_cap)
+            polls_sum = s(jnp.where(loc_exec_pess, polls, 0))
+            writes += m_pe
+            cas += 3 * m_pe + polls_sum                  # lock + ptr + unlock + polls
+            retries_total += polls_sum
+            mn_bytes += m_pe * (cfg.value_bytes + 3 * cfg.ptr_bytes) + polls_sum * cfg.ptr_bytes
+            per_op_retries = jnp.where(loc_exec_pess, polls, per_op_retries)
+            per_op_rank = jnp.where(loc_exec_pess, plan_p.rank_of, per_op_rank)
+        elif cfg.mode == SyncMode.MCS:
+            writes += m_pe
+            cas += 2 * m_pe                              # enqueue masked-CAS + ptr CAS
+            faa += m_pe                                  # epoch release
+            plan_p = wc.stats_from_plan(plan_all, loc_exec_pess)
+            cn_msgs += 2 * s(jnp.where(loc_exec_pess, (plan_p.mult_of > 1), 0))
+            mn_bytes += m_pe * (cfg.value_bytes + 2 * cfg.ptr_bytes + 8)
+            per_op_batch = jnp.where(loc_exec_pess, 1, per_op_batch)
+            per_op_rank = jnp.where(loc_exec_pess, plan_p.rank_of, per_op_rank)
+        elif cfg.mode == SyncMode.CIDER:
+            # global WC: all queued writers on a key collapse to ONE executed write
+            plan_p = wc.stats_from_plan(plan_all, loc_exec_pess)
+            is_exec = loc_exec_pess & plan_p.is_tail     # queue tail = executor
+            n_q = s(is_exec)                             # number of wait queues
+            multi = loc_exec_pess & (plan_p.mult_of > 1)
+            n_multi_q = s(is_exec & (plan_p.mult_of > 1))
+            cas += m_pe + n_q                            # m enqueues + 1 ptr CAS per queue
+            writes += n_q                                # ONE combined write per queue
+            faa += m_pe                                  # every client's release FAA
+            reads += n_multi_q                           # coordinator tail lookup (step 1)
+            cn_msgs += s(jnp.where(is_exec & (plan_p.mult_of > 1),
+                                   plan_p.mult_of + 1, 0))
+            mn_bytes += (m_pe * cfg.ptr_bytes + n_q * (cfg.value_bytes + cfg.ptr_bytes)
+                         + m_pe * 8 + n_multi_q * cfg.lock_bytes)
+            ro_bytes += n_multi_q * cfg.lock_bytes       # coordinator tail READ
+            combined_total += s(pess) - n_q
+            per_op_combined = per_op_combined | (pess & ~is_exec)
+            per_op_batch = jnp.where(loc_exec_pess, plan_p.mult_of, per_op_batch)
+            per_op_rank = jnp.where(loc_exec_pess, plan_p.rank_of, per_op_rank)
 
-    executed = writes
+        executed = writes
 
     # ---- 5b. lease/epoch orphaned-lock repair (crash recovery, §4.6) ------
     # A CN that dies mid-window strands the locks it held or was queued on.
@@ -517,83 +522,84 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
     # Mode asymmetry — the recovery result: CIDER's combined queue has ONE
     # lock entry per queue and SPIN one lock word per key, while MCS strands
     # the whole chain of dead queue nodes, each repaired by its successor.
-    per_op_orphan = jnp.zeros((b,), jnp.int32)
-    repair_total = jnp.zeros((), i64)
-    orphan_out = jnp.zeros((), i64)
-    if cfg.mode == SyncMode.OSYNC:
-        stranded = state.stranded          # lock-free: crashes strand nothing
-    else:
-        slot_u = jnp.clip(keys - base, 0, cfg.n_slots - 1)
-        if died is None:
-            add_slot = jnp.zeros((cfg.n_slots,), jnp.int32)
+    with jax.named_scope("engine.s5b_repair"):
+        per_op_orphan = jnp.zeros((b,), jnp.int32)
+        repair_total = jnp.zeros((), i64)
+        orphan_out = jnp.zeros((), i64)
+        if cfg.mode == SyncMode.OSYNC:
+            stranded = state.stranded          # lock-free: crashes strand nothing
         else:
-            d = jnp.asarray(died, bool)
-            dead_w = (present & d[jnp.clip(cn, 0, d.shape[0] - 1)]
-                      & ((kinds == OpKind.UPDATE) | (kinds == OpKind.DELETE)))
-            if owned is not None:
-                dead_w = dead_w & owned
-            if cfg.mode == SyncMode.CIDER:
-                # only writers the credit table routes pessimistically held a
-                # lock when they crashed; a dead optimistic writer strands
-                # nothing (its out-of-place CAS simply never lands) — the
-                # lock-free-crash argument FUSEE makes.  DELETEs always lock.
-                cslot = credit_slot(keys, credits.credit.shape[0])
-                dead_node = dead_w & ((credits.credit[cslot] > 0)
-                                      | (kinds == OpKind.DELETE))
-            elif cfg.local_wc:
-                # only the local executor of each (key, CN) UPDATE group had
-                # left the crashed CN for the memory pool; DELETEs are never
-                # locally combined (they lock independently on the live path
-                # too), so each dead DELETE strands its own node.  `died`
-                # is in scope here, so the static-CN scatter grouping always
-                # applies — no extra sort on the recovery plane.
-                dead_upd = dead_w & (kinds == OpKind.UPDATE)
-                dead_node = (_group_last(dead_upd)
-                             | (dead_w & (kinds == OpKind.DELETE)))
+            slot_u = jnp.clip(keys - base, 0, cfg.n_slots - 1)
+            if died is None:
+                add_slot = jnp.zeros((cfg.n_slots,), jnp.int32)
             else:
-                dead_node = dead_w
-            # stranded-node count per slot: dead nodes are keyed by slot
-            # already, so the per-key multiplicity-at-tail reduction the
-            # sort-based stats would compute IS this one scatter-add
-            # (MCS strands the whole chain; SPIN/CIDER one word/entry)
-            cnt = jnp.zeros((cfg.n_slots,), jnp.int32).at[slot_u].add(
-                dead_node.astype(jnp.int32))
-            add_slot = (cnt if cfg.mode == SyncMode.MCS
-                        else jnp.minimum(cnt, 1))
-        tot = state.stranded + add_slot
-        if cfg.mode != SyncMode.MCS:
-            tot = jnp.minimum(tot, 1)      # one lock word/entry per key
-        # any surviving locker on the slot repairs all its stranded nodes
-        # this window; untouched slots stay stranded for their next locker
-        surv = loc_exec_pess | is_delete
-        surv_slot = jnp.zeros((cfg.n_slots,), bool).at[
-            jnp.where(surv, slot_u, cfg.n_slots)].set(True, mode="drop")
-        repaired = surv_slot & (tot > 0)
-        n_repair = jnp.sum(jnp.where(repaired, tot, 0).astype(i64))
-        stranded = jnp.where(repaired, 0, tot)
-        orphan_out = jnp.sum((stranded > 0).astype(i64))
-        per_op_orphan = jnp.where(surv & repaired[slot_u], tot[slot_u], 0)
-        # bill: one stale-epoch READ of the lock entry + one break CAS per
-        # stranded node, charged to the blocked queue
-        reads += n_repair
-        cas += n_repair
-        repair_total += n_repair
-        mn_bytes += n_repair * (cfg.lock_bytes + 8)
-        ro_bytes += n_repair * cfg.lock_bytes        # stale-epoch detection READ
-        if cfg.mode == SyncMode.SPIN:
-            # spinners keep re-CASing the orphaned word until the lease
-            # expires — MN verbs MCS/CIDER waiters never issue (they wait
-            # CN-locally); these polls ARE recovery overhead, so they are
-            # folded into repair_cas as well
-            pollc = _backoff_polls(jnp.asarray(cfg.lease_poll_rounds, jnp.int32),
-                                   cfg.backoff_cap)
-            lease_polls = per_op_orphan * pollc
-            polls_lease = s(lease_polls)
-            cas += polls_lease
-            retries_total += polls_lease
-            repair_total += polls_lease
-            mn_bytes += polls_lease * cfg.ptr_bytes
-            per_op_retries = per_op_retries + lease_polls
+                d = jnp.asarray(died, bool)
+                dead_w = (present & d[jnp.clip(cn, 0, d.shape[0] - 1)]
+                          & ((kinds == OpKind.UPDATE) | (kinds == OpKind.DELETE)))
+                if owned is not None:
+                    dead_w = dead_w & owned
+                if cfg.mode == SyncMode.CIDER:
+                    # only writers the credit table routes pessimistically held a
+                    # lock when they crashed; a dead optimistic writer strands
+                    # nothing (its out-of-place CAS simply never lands) — the
+                    # lock-free-crash argument FUSEE makes.  DELETEs always lock.
+                    cslot = credit_slot(keys, credits.credit.shape[0])
+                    dead_node = dead_w & ((credits.credit[cslot] > 0)
+                                          | (kinds == OpKind.DELETE))
+                elif cfg.local_wc:
+                    # only the local executor of each (key, CN) UPDATE group had
+                    # left the crashed CN for the memory pool; DELETEs are never
+                    # locally combined (they lock independently on the live path
+                    # too), so each dead DELETE strands its own node.  `died`
+                    # is in scope here, so the static-CN scatter grouping always
+                    # applies — no extra sort on the recovery plane.
+                    dead_upd = dead_w & (kinds == OpKind.UPDATE)
+                    dead_node = (_group_last(dead_upd)
+                                 | (dead_w & (kinds == OpKind.DELETE)))
+                else:
+                    dead_node = dead_w
+                # stranded-node count per slot: dead nodes are keyed by slot
+                # already, so the per-key multiplicity-at-tail reduction the
+                # sort-based stats would compute IS this one scatter-add
+                # (MCS strands the whole chain; SPIN/CIDER one word/entry)
+                cnt = jnp.zeros((cfg.n_slots,), jnp.int32).at[slot_u].add(
+                    dead_node.astype(jnp.int32))
+                add_slot = (cnt if cfg.mode == SyncMode.MCS
+                            else jnp.minimum(cnt, 1))
+            tot = state.stranded + add_slot
+            if cfg.mode != SyncMode.MCS:
+                tot = jnp.minimum(tot, 1)      # one lock word/entry per key
+            # any surviving locker on the slot repairs all its stranded nodes
+            # this window; untouched slots stay stranded for their next locker
+            surv = loc_exec_pess | is_delete
+            surv_slot = jnp.zeros((cfg.n_slots,), bool).at[
+                jnp.where(surv, slot_u, cfg.n_slots)].set(True, mode="drop")
+            repaired = surv_slot & (tot > 0)
+            n_repair = jnp.sum(jnp.where(repaired, tot, 0).astype(i64))
+            stranded = jnp.where(repaired, 0, tot)
+            orphan_out = jnp.sum((stranded > 0).astype(i64))
+            per_op_orphan = jnp.where(surv & repaired[slot_u], tot[slot_u], 0)
+            # bill: one stale-epoch READ of the lock entry + one break CAS per
+            # stranded node, charged to the blocked queue
+            reads += n_repair
+            cas += n_repair
+            repair_total += n_repair
+            mn_bytes += n_repair * (cfg.lock_bytes + 8)
+            ro_bytes += n_repair * cfg.lock_bytes        # stale-epoch detection READ
+            if cfg.mode == SyncMode.SPIN:
+                # spinners keep re-CASing the orphaned word until the lease
+                # expires — MN verbs MCS/CIDER waiters never issue (they wait
+                # CN-locally); these polls ARE recovery overhead, so they are
+                # folded into repair_cas as well
+                pollc = _backoff_polls(jnp.asarray(cfg.lease_poll_rounds, jnp.int32),
+                                       cfg.backoff_cap)
+                lease_polls = per_op_orphan * pollc
+                polls_lease = s(lease_polls)
+                cas += polls_lease
+                retries_total += polls_lease
+                repair_total += polls_lease
+                mn_bytes += polls_lease * cfg.ptr_bytes
+                per_op_retries = per_op_retries + lease_polls
 
     # ---- 5c. SCAN reader probes (range reads, DESIGN.md §9, §10.3) --------
     # A SCAN(key, count) expands into `count` reader probes over the
@@ -607,87 +613,88 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
     # slot_base + n_slots) belong to another shard (or fall off the keyspace
     # end): each shard counts its own sub-run and the dist psum reassembles
     # the rows.
-    if cfg.scan_max > 0:
-        ns = cfg.scan_max
-        is_scan = (kinds == OpKind.SCAN) & valid
-        count = jnp.clip(values, 0, ns)               # count rides `values`
-        jj = jnp.arange(ns, dtype=jnp.int32)
-        pk = keys[:, None] + jj[None, :]              # (B, ns) global slots
-        p_loc = pk - base
-        p_in = (is_scan[:, None] & (jj[None, :] < count[:, None])
-                & (p_loc >= 0) & (p_loc < cfg.n_slots))
-        keys_p = pk.reshape(b * ns)
-        pos_p = jnp.broadcast_to(pos[:, None], (b, ns)).reshape(b * ns)
-        pv = p_in.reshape(b * ns)
-        keys_c = jnp.concatenate([keys, keys_p])
-        pos_c = jnp.concatenate([pos, pos_p])
-        tvalid = jnp.concatenate([valid_o, pv])
-        plan_c = wc.plan_combine(keys_c, pos_c, tvalid,
-                                 backend=cfg.kernel_backend)
-        pc = plan_c.perm
-        bc = b * (1 + ns)
-        # existence transfers: only this window's INSERT/DELETE lanes set the
-        # bit; probes (readers) and UPDATEs are identity — so the probe pass
-        # needs no value plane at all
-        setcode_c = jnp.concatenate(
-            [jnp.where(is_insert, jnp.int32(1),
-                       jnp.where(is_delete, jnp.int32(0), jnp.int32(-1))),
-             jnp.full((b * ns,), -1, jnp.int32)])
-        lockw = loc_exec_pess | is_delete             # exclusive lock holders
-        writer_c = jnp.concatenate([lockw, jnp.zeros((b * ns,), bool)])
-        slot_c = jnp.clip(keys_c[pc] - base, 0, cfg.n_slots - 1)
-        e_init_c = state.ptr[slot_c] != NULL_PTR
-        e_bc, waits_s = _probe_sweep(plan_c.keys_sorted, setcode_c[pc],
-                                     writer_c[pc], e_init_c,
-                                     cfg.kernel_backend)
-        v_sc = tvalid[pc]
-        e_probe = jnp.zeros((bc,), bool).at[pc].set(e_bc & v_sc)
-        hit = e_probe[b:].reshape(b, ns) & p_in
-        per_op_rows = jnp.sum(hit.astype(jnp.int32), axis=1)
-        n_probes = s(pv)
-        n_rows = s(hit)
-        # base bill: one leaf-entry READ per probed slot + one value READ per
-        # row found (every mode traverses the same run)
-        reads += n_probes + n_rows
-        mn_bytes += n_probes * cfg.ptr_bytes + n_rows * cfg.value_bytes
-        ro_bytes += n_probes * cfg.ptr_bytes + n_rows * cfg.value_bytes
-        if cfg.mode == SyncMode.OSYNC:
-            # optimistic traversal must re-read each leaf's version to
-            # validate against concurrent pointer swaps (§2.2's cost, paid
-            # per leaf whether or not anyone wrote)
-            reads += n_probes
-            mn_bytes += n_probes * cfg.ptr_bytes
-            ro_bytes += n_probes * cfg.ptr_bytes
-        elif cfg.mode == SyncMode.SPIN:
-            # a CAS spinlock has no shared mode: lock + unlock CAS per leaf
-            cas += 2 * n_probes
-            mn_bytes += 2 * n_probes * cfg.ptr_bytes
-        elif cfg.mode == SyncMode.MCS:
-            # lock-shared traversal: shared-mode enqueue CAS + release FAA
-            # per leaf (the epoch heartbeat plane tracks exclusive holders
-            # only — the reader FAA is billed, not recorded)
-            cas += n_probes
-            faa += n_probes
-            mn_bytes += n_probes * (cfg.ptr_bytes + 8)
-        else:  # CIDER: consult the CN-local credit table (free) — cold
-               # leaves are traversed lock-free like OSYNC *without* the
-               # re-read (the table certifies no concurrent pessimistic
-               # writer), hot leaves join the queue in shared mode
-            cslot_p = credit_slot(keys_p, credits.credit.shape[0])
-            hot_p = pv & (credits.credit[cslot_p] > 0)
-            n_hot = s(hot_p)
-            cas += n_hot
-            faa += n_hot
-            mn_bytes += n_hot * (cfg.lock_bytes + 8)
-        if cfg.mode != SyncMode.OSYNC:
-            # wait rank of the anchor-leaf reader behind exclusive holders
-            # (queue order == batch position now includes reader ranks) —
-            # already computed by the fused pass above; just unsort it
-            readers_s = jnp.concatenate([jnp.zeros((b,), bool), pv])[pc]
-            waits = jnp.zeros((bc,), jnp.int32).at[pc].set(
-                jnp.where(readers_s, waits_s, 0))
-            per_op_rank = jnp.where(p_in[:, 0], waits[b:].reshape(b, ns)[:, 0],
-                                    per_op_rank)
+    with jax.named_scope("engine.s5c_scan"):
+        if cfg.scan_max > 0:
+            ns = cfg.scan_max
+            is_scan = (kinds == OpKind.SCAN) & valid
+            count = jnp.clip(values, 0, ns)               # count rides `values`
+            jj = jnp.arange(ns, dtype=jnp.int32)
+            pk = keys[:, None] + jj[None, :]              # (B, ns) global slots
+            p_loc = pk - base
+            p_in = (is_scan[:, None] & (jj[None, :] < count[:, None])
+                    & (p_loc >= 0) & (p_loc < cfg.n_slots))
+            keys_p = pk.reshape(b * ns)
+            pos_p = jnp.broadcast_to(pos[:, None], (b, ns)).reshape(b * ns)
+            pv = p_in.reshape(b * ns)
+            keys_c = jnp.concatenate([keys, keys_p])
+            pos_c = jnp.concatenate([pos, pos_p])
+            tvalid = jnp.concatenate([valid_o, pv])
+            plan_c = wc.plan_combine(keys_c, pos_c, tvalid,
+                                     backend=cfg.kernel_backend)
+            pc = plan_c.perm
+            bc = b * (1 + ns)
+            # existence transfers: only this window's INSERT/DELETE lanes set the
+            # bit; probes (readers) and UPDATEs are identity — so the probe pass
+            # needs no value plane at all
+            setcode_c = jnp.concatenate(
+                [jnp.where(is_insert, jnp.int32(1),
+                           jnp.where(is_delete, jnp.int32(0), jnp.int32(-1))),
+                 jnp.full((b * ns,), -1, jnp.int32)])
+            lockw = loc_exec_pess | is_delete             # exclusive lock holders
+            writer_c = jnp.concatenate([lockw, jnp.zeros((b * ns,), bool)])
+            slot_c = jnp.clip(keys_c[pc] - base, 0, cfg.n_slots - 1)
+            e_init_c = state.ptr[slot_c] != NULL_PTR
+            e_bc, waits_s = _probe_sweep(plan_c.keys_sorted, setcode_c[pc],
+                                         writer_c[pc], e_init_c,
+                                         cfg.kernel_backend)
+            v_sc = tvalid[pc]
+            e_probe = jnp.zeros((bc,), bool).at[pc].set(e_bc & v_sc)
+            hit = e_probe[b:].reshape(b, ns) & p_in
+            per_op_rows = jnp.sum(hit.astype(jnp.int32), axis=1)
+            n_probes = s(pv)
+            n_rows = s(hit)
+            # base bill: one leaf-entry READ per probed slot + one value READ per
+            # row found (every mode traverses the same run)
+            reads += n_probes + n_rows
+            mn_bytes += n_probes * cfg.ptr_bytes + n_rows * cfg.value_bytes
+            ro_bytes += n_probes * cfg.ptr_bytes + n_rows * cfg.value_bytes
+            if cfg.mode == SyncMode.OSYNC:
+                # optimistic traversal must re-read each leaf's version to
+                # validate against concurrent pointer swaps (§2.2's cost, paid
+                # per leaf whether or not anyone wrote)
+                reads += n_probes
+                mn_bytes += n_probes * cfg.ptr_bytes
+                ro_bytes += n_probes * cfg.ptr_bytes
+            elif cfg.mode == SyncMode.SPIN:
+                # a CAS spinlock has no shared mode: lock + unlock CAS per leaf
+                cas += 2 * n_probes
+                mn_bytes += 2 * n_probes * cfg.ptr_bytes
+            elif cfg.mode == SyncMode.MCS:
+                # lock-shared traversal: shared-mode enqueue CAS + release FAA
+                # per leaf (the epoch heartbeat plane tracks exclusive holders
+                # only — the reader FAA is billed, not recorded)
+                cas += n_probes
+                faa += n_probes
+                mn_bytes += n_probes * (cfg.ptr_bytes + 8)
+            else:  # CIDER: consult the CN-local credit table (free) — cold
+                   # leaves are traversed lock-free like OSYNC *without* the
+                   # re-read (the table certifies no concurrent pessimistic
+                   # writer), hot leaves join the queue in shared mode
+                cslot_p = credit_slot(keys_p, credits.credit.shape[0])
+                hot_p = pv & (credits.credit[cslot_p] > 0)
+                n_hot = s(hot_p)
+                cas += n_hot
+                faa += n_hot
+                mn_bytes += n_hot * (cfg.lock_bytes + 8)
+            if cfg.mode != SyncMode.OSYNC:
+                # wait rank of the anchor-leaf reader behind exclusive holders
+                # (queue order == batch position now includes reader ranks) —
+                # already computed by the fused pass above; just unsort it
+                readers_s = jnp.concatenate([jnp.zeros((b,), bool), pv])[pc]
+                waits = jnp.zeros((bc,), jnp.int32).at[pc].set(
+                    jnp.where(readers_s, waits_s, 0))
+                per_op_rank = jnp.where(p_in[:, 0], waits[b:].reshape(b, ns)[:, 0],
+                                        per_op_rank)
 
     # ---- 5d. SNAPSHOT replica fan-out (FUSEE; DESIGN.md §13) --------------
     # Client-centric replication: the client issues every write-class verb
@@ -697,74 +704,78 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
     # word — while reads go to one replica.  Static branch: R=1 compiles to
     # the byte-identical pre-replication program (tests/test_replication.py).
     # Per-op Results are logical-op observables and never scale.
-    if cfg.n_replicas > 1:
-        rep = cfg.n_replicas
-        writes = writes * rep
-        cas = cas * rep
-        faa = faa * rep
-        retries_total = retries_total * rep
-        repair_total = repair_total * rep
-        mn_bytes = ro_bytes + rep * (mn_bytes - ro_bytes)
+    with jax.named_scope("engine.s5d_replicas"):
+        if cfg.n_replicas > 1:
+            rep = cfg.n_replicas
+            writes = writes * rep
+            cas = cas * rep
+            faa = faa * rep
+            retries_total = retries_total * rep
+            repair_total = repair_total * rep
+            mn_bytes = ro_bytes + rep * (mn_bytes - ro_bytes)
 
     # ---- 6. credit feedback (§4.3, Algorithm 1 lines 13-22) ---------------
     # Like the decision, feedback runs on the FULL window so replicated
     # credit tables stay identical across shards; when unsharded the full
     # masks ARE the owned masks and nothing is recomputed.
-    if cfg.mode == SyncMode.CIDER:
-        if owned is None:
-            pess_fb, batch_fb = loc_exec_pess, per_op_batch
-            opt_fb, retry_fb = loc_exec_opt | is_insert, per_op_retries
-        else:
-            # full-window masks are subsets of `valid` (not valid_o), so the
-            # replicated credit plane pays one full-validity plan of each
-            # kind and derives both feedback stats from it (DESIGN.md §10.2)
-            opt_upd_full = upd_full & ~pess_full
-            if cfg.local_wc:
-                gplan_full = wc.plan_groups(keys, cn, pos, valid)
-                loc_opt_full = wc.group_last(gplan_full, opt_upd_full)
+    with jax.named_scope("engine.s6_feedback"):
+        if cfg.mode == SyncMode.CIDER:
+            if owned is None:
+                pess_fb, batch_fb = loc_exec_pess, per_op_batch
+                opt_fb, retry_fb = loc_exec_opt | is_insert, per_op_retries
             else:
-                loc_opt_full = opt_upd_full
-            plan_full = wc.plan_combine(keys, pos, valid,
-                                        backend=cfg.kernel_backend)
-            plan_p_fb = wc.stats_from_plan(plan_full, pess_full)
-            plan_o_fb = wc.stats_from_plan(plan_full, loc_opt_full)
-            pess_fb = pess_full
-            batch_fb = jnp.where(pess_full, plan_p_fb.mult_of, 1)
-            opt_fb = loc_opt_full | ((kinds == OpKind.INSERT) & valid)
-            retry_fb = jnp.where(loc_opt_full, plan_o_fb.rank_of, 0)
-        credits3 = credit_feedback(
-            credits2, keys, credits.credit.shape[0],
-            pess=pess_fb, wc_batch=batch_fb,
-            opt=opt_fb, n_retry=retry_fb,
-            initial_credit=cfg.initial_credit,
-            hotness_threshold=cfg.hotness_threshold,
-            aimd_factor=cfg.aimd_factor)
-    else:
-        credits3 = credits2
+                # full-window masks are subsets of `valid` (not valid_o), so the
+                # replicated credit plane pays one full-validity plan of each
+                # kind and derives both feedback stats from it (DESIGN.md §10.2)
+                opt_upd_full = upd_full & ~pess_full
+                if cfg.local_wc:
+                    gplan_full = wc.plan_groups(keys, cn, pos, valid)
+                    loc_opt_full = wc.group_last(gplan_full, opt_upd_full)
+                else:
+                    loc_opt_full = opt_upd_full
+                plan_full = wc.plan_combine(keys, pos, valid,
+                                            backend=cfg.kernel_backend)
+                plan_p_fb = wc.stats_from_plan(plan_full, pess_full)
+                plan_o_fb = wc.stats_from_plan(plan_full, loc_opt_full)
+                pess_fb = pess_full
+                batch_fb = jnp.where(pess_full, plan_p_fb.mult_of, 1)
+                opt_fb = loc_opt_full | ((kinds == OpKind.INSERT) & valid)
+                retry_fb = jnp.where(loc_opt_full, plan_o_fb.rank_of, 0)
+            credits3 = credit_feedback(
+                credits2, keys, credits.credit.shape[0],
+                pess=pess_fb, wc_batch=batch_fb,
+                opt=opt_fb, n_retry=retry_fb,
+                initial_credit=cfg.initial_credit,
+                hotness_threshold=cfg.hotness_threshold,
+                aimd_factor=cfg.aimd_factor)
+        else:
+            credits3 = credits2
 
     # ---- 7. epoch FAA bookkeeping (fault-tolerance heartbeat, §4.6) -------
-    if cfg.mode in (SyncMode.MCS, SyncMode.CIDER):
-        rel = loc_exec_pess | is_delete
-        epoch = state.epoch.at[jnp.where(rel, keys - base, cfg.n_slots)].add(
-            rel.astype(jnp.int32), mode="drop")
-    else:
-        epoch = state.epoch
+    with jax.named_scope("engine.s7_epoch"):
+        if cfg.mode in (SyncMode.MCS, SyncMode.CIDER):
+            rel = loc_exec_pess | is_delete
+            epoch = state.epoch.at[jnp.where(rel, keys - base, cfg.n_slots)].add(
+                rel.astype(jnp.int32), mode="drop")
+        else:
+            epoch = state.epoch
 
-    new_state = StoreState(ptr=ptr, meta=pack_meta(ver, stranded),
-                           epoch=epoch, heap=heap,
-                           heap_top=state.heap_top + n_commits)
-    # unsort results
-    ok = jnp.zeros((b,), bool).at[perm].set(ok_s)
-    # SCAN succeeds when it found any row; per-shard partial counts OR
-    # together under the dist psum exactly as the totals add
-    ok = ok | (per_op_rows > 0)
-    value = jnp.full((b,), _NONE, jnp.int32).at[perm].set(val_s)
-    res = Results(ok=ok, value=value, pessimistic=pess,
-                  combined=per_op_combined, wc_batch=per_op_batch,
-                  retries=per_op_retries, rank=per_op_rank,
-                  orphan_wait=per_op_orphan, rows=per_op_rows)
-    io = IOMetrics(reads=reads, writes=writes, cas=cas, faa=faa,
-                   cn_msgs=cn_msgs, mn_bytes=mn_bytes, retries=retries_total,
-                   combined=combined_total, executed=executed,
-                   repair_cas=repair_total, orphan_windows=orphan_out)
+    with jax.named_scope("engine.s8_assemble"):
+        new_state = StoreState(ptr=ptr, meta=pack_meta(ver, stranded),
+                               epoch=epoch, heap=heap,
+                               heap_top=state.heap_top + n_commits)
+        # unsort results
+        ok = jnp.zeros((b,), bool).at[perm].set(ok_s)
+        # SCAN succeeds when it found any row; per-shard partial counts OR
+        # together under the dist psum exactly as the totals add
+        ok = ok | (per_op_rows > 0)
+        value = jnp.full((b,), _NONE, jnp.int32).at[perm].set(val_s)
+        res = Results(ok=ok, value=value, pessimistic=pess,
+                      combined=per_op_combined, wc_batch=per_op_batch,
+                      retries=per_op_retries, rank=per_op_rank,
+                      orphan_wait=per_op_orphan, rows=per_op_rows)
+        io = IOMetrics(reads=reads, writes=writes, cas=cas, faa=faa,
+                       cn_msgs=cn_msgs, mn_bytes=mn_bytes, retries=retries_total,
+                       combined=combined_total, executed=executed,
+                       repair_cas=repair_total, orphan_windows=orphan_out)
     return new_state, credits3, res, io

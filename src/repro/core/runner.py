@@ -75,6 +75,7 @@ class WindowStream:
         return self.batch.kinds.shape
 
 
+@functools.partial(jax.profiler.annotate_function, name="runner.make_stream")
 def make_stream(kinds, keys, values, n_cns: int = 1,
                 lanes_per_cn: int | None = None,
                 valid: jax.Array | None = None,
@@ -161,6 +162,7 @@ def _prev_alive(stream: WindowStream, prev_alive) -> jax.Array:
                                                                   bool)
 
 
+@functools.partial(jax.profiler.annotate_function, name="runner.dispatch")
 def run_windows(cfg: EngineConfig, state: StoreState, credits: CreditState,
                 stream: WindowStream, io_per_window: bool = False,
                 prev_alive: jax.Array | None = None,

@@ -329,6 +329,7 @@ def apply_batch_sharded(cfg: EngineConfig, mesh, state: StoreState,
     return _sharded_fn(cfg, mesh, axis)(state, credits, batch, valid)
 
 
+@functools.partial(jax.profiler.annotate_function, name="runner.dispatch")
 def run_windows_sharded(cfg: EngineConfig, mesh, state: StoreState,
                         credits, stream: WindowStream, *, axis: str = "data",
                         io_per_window: bool = False,

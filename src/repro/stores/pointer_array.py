@@ -10,6 +10,9 @@ out-of-place KV pair in the heap.
 from __future__ import annotations
 
 import dataclasses
+import functools
+
+import jax
 
 from repro.core import engine, runner
 from repro.core.credits import CreditState, credit_init
@@ -19,6 +22,7 @@ from repro.core.types import (EngineConfig, IOMetrics, OpBatch, OpKind,
 __all__ = ["PointerArray"]
 
 
+@functools.partial(jax.profiler.annotate_function, name="store.scan_check")
 def _reject_scan(kinds) -> None:
     """Point-op stores cannot serve range reads — fail loudly, not with a
     silent 0-row result (DESIGN.md §9)."""
@@ -56,6 +60,7 @@ class PointerArray:
             self.cfg, self.state, self.credits, batch)
         return dataclasses.replace(self, state=state, credits=credits), res, io
 
+    @functools.partial(jax.profiler.annotate_function, name="store.apply_stream")
     def apply_stream(self, stream: runner.WindowStream, io_per_window: bool = False
                      ) -> tuple["PointerArray", engine.Results, IOMetrics]:
         """Run every window of ``stream`` in one fused scan (``run_windows``).

@@ -28,6 +28,7 @@ CIDER optimizes; we fix 256-ary nodes and fold cache-miss traffic into
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +86,7 @@ class SmartART:
             self.cfg, self.state, self.credits, batch)
         return dataclasses.replace(self, state=state, credits=credits), res, io
 
+    @functools.partial(jax.profiler.annotate_function, name="store.apply_stream")
     def apply_stream(self, kinds, keys, values, n_cns: int = 1,
                      io_per_window: bool = False
                      ) -> tuple["SmartART", engine.Results, IOMetrics]:
@@ -92,10 +94,11 @@ class SmartART:
         through the radix path, then one ``run_windows`` scan executes every
         window on-device.  Buffers are donated — use the returned instance.
         """
-        kinds = jnp.asarray(kinds, jnp.int32)
-        values = jnp.asarray(values, jnp.int32)
-        stream = runner.make_stream(kinds, self.slots(keys), values,
-                                    n_cns=n_cns)
+        with jax.profiler.TraceAnnotation("store.slots"):
+            kinds = jnp.asarray(kinds, jnp.int32)
+            values = jnp.asarray(values, jnp.int32)
+            slots = self.slots(keys)
+        stream = runner.make_stream(kinds, slots, values, n_cns=n_cns)
         state, credits, res, io = runner.run_windows(
             self.cfg, self.state, self.credits, stream,
             io_per_window=io_per_window)
