@@ -17,7 +17,8 @@ path below — bit-identical by contract and by test.
 
 DESIGN.md §2.1 (the combine primitive): one lexsort materializes every wait
 queue; reader ranks extend it to SCAN (§9.2); §10 covers backend dispatch
-and the shared-sort derived plans (``stats_from_plan``, ``plan_groups``).
+and the shared-sort derived plans (``stats_from_plan``, ``plan_groups``,
+``key_run_ids``).
 """
 from __future__ import annotations
 
@@ -28,8 +29,8 @@ import jax.numpy as jnp
 
 __all__ = ["CombinePlan", "plan_combine", "segment_last", "segment_counts",
            "OpStats", "per_key_stats", "stats_from_plan", "GroupPlan",
-           "plan_groups", "group_last", "local_executors", "reader_waits",
-           "resolve_backend"]
+           "plan_groups", "group_last", "local_executors", "key_run_ids",
+           "local_executors_by_run", "reader_waits", "resolve_backend"]
 
 _BIG = 2**31 - 1   # python int, weak-typed to int32 at use sites
 
@@ -228,26 +229,35 @@ def local_executors(keys: jax.Array, cn: jax.Array, pos: jax.Array,
     return group_last(plan_groups(keys, cn, pos, mask), mask)
 
 
-def local_executors_scatter(keys: jax.Array, cn: jax.Array, pos: jax.Array,
-                            mask: jax.Array, n_slots: int, n_cns: int,
-                            base=0) -> jax.Array:
-    """Sort-free ``local_executors``: one O(B) scatter-max over a static
-    ``(n_slots * n_cns,)`` cell table instead of a (key, cn, pos) lexsort.
+def key_run_ids(plan: CombinePlan) -> jax.Array:
+    """Per-lane key-run id in batch order: the sorted index of the first
+    lane of the lane's ``plan`` run, in ``[0, B)``.  Two lanes of the plan's
+    validity share an id iff they share a key; invalid lanes all share the
+    +inf tail run's id."""
+    b = plan.perm.shape[0]
+    first = jnp.arange(b, dtype=jnp.int32) - plan.rank
+    return jnp.zeros((b,), jnp.int32).at[plan.perm].set(first)
 
-    Bit-identical to ``local_executors`` under the ``OpBatch`` contract the
-    engine already relies on — ``pos`` unique per batch (serialization
-    priorities 0..B-1) and ``cn ∈ [0, n_cns)`` (``OpBatch.make`` takes cn
-    mod n_cns; the liveness plane clips the same way): the unique max-pos
-    masked lane of each (key, cn) cell IS the stable sort's group tail.
-    The engine picks this form whenever a static CN count is in scope
-    (``alive``/``died`` carry it as their shape) — DESIGN.md §10.2.
-    ``base`` rebases global keys to shard-local cells under sharding; lanes
-    outside ``mask`` never touch the table, so out-of-shard keys are inert.
+
+def local_executors_by_run(run_id: jax.Array, cn: jax.Array, pos: jax.Array,
+                           mask: jax.Array, n_cns: int) -> jax.Array:
+    """Sort-free ``local_executors``: one O(B) scatter-max over a static
+    ``(B * n_cns,)`` cell table keyed by (key run, cn) instead of a
+    (key, cn, pos) lexsort.
+
+    ``run_id`` comes from ``key_run_ids`` of a plan whose validity covers
+    ``mask``, so masked lanes share a cell iff they share (key, cn); the
+    table's size depends on the window and the CN count, never on the
+    store.  Bit-identical to ``local_executors`` under the ``OpBatch``
+    contract the engine already relies on — ``pos`` unique per batch
+    (serialization priorities 0..B-1) and ``cn ∈ [0, n_cns)``
+    (``OpBatch.make`` takes cn mod n_cns; the liveness plane clips the same
+    way): the unique max-pos masked lane of each cell IS the stable sort's
+    group tail.  DESIGN.md §10.2.
     """
-    slot = jnp.clip(keys - base, 0, n_slots - 1)
-    gi = slot * n_cns + jnp.clip(cn, 0, n_cns - 1)
-    buf = jnp.full((n_slots * n_cns,), -1, jnp.int32)
-    buf = buf.at[gi].max(jnp.where(mask, pos, -1), mode="drop")
+    gi = run_id * n_cns + jnp.clip(cn, 0, n_cns - 1)
+    buf = jnp.full((run_id.shape[0] * n_cns,), -1, jnp.int32)
+    buf = buf.at[gi].max(jnp.where(mask, pos, -1))
     return mask & (buf[gi] == pos)
 
 

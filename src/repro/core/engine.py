@@ -382,11 +382,11 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
     # and global WC subsumes local WC.
     # Two bit-identical groupings (DESIGN.md §10.2): with a static CN count
     # in scope (the liveness plane's shape — always true on the fused
-    # runner/bench path) each masked subset costs one O(B) scatter-max over
-    # (key, cn) cells; otherwise one (key, CN, pos) sort over the owned
-    # window serves every subset — both executor masks are subsets of
-    # valid_o, so group_last on the shared plan matches a dedicated
-    # local_executors sort per mask.
+    # runner/bench path) each masked subset costs one O(B) scatter-max over a
+    # (B * n_cns) cell table keyed by the lane's key run in plan_all;
+    # otherwise one (key, CN, pos) sort over the owned window serves every
+    # subset.  Both executor masks are subsets of valid_o, so either matches
+    # a dedicated local_executors sort per mask.
     with jax.named_scope("engine.s4_local_wc"):
         n_cns_static = None
         for liveness in (alive, died):
@@ -394,9 +394,11 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
                 n_cns_static = jnp.asarray(liveness).shape[0]
                 break
         if n_cns_static is not None:
+            run_id = wc.key_run_ids(plan_all) if cfg.local_wc else None
+
             def _group_last(mask):
-                return wc.local_executors_scatter(keys, cn, pos, mask,
-                                                  cfg.n_slots, n_cns_static, base)
+                return wc.local_executors_by_run(run_id, cn, pos, mask,
+                                                 n_cns_static)
         else:
             gplan = wc.plan_groups(keys, cn, pos, valid_o) if cfg.local_wc else None
 
@@ -550,11 +552,11 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
                     # only the local executor of each (key, CN) UPDATE group had
                     # left the crashed CN for the memory pool; DELETEs are never
                     # locally combined (they lock independently on the live path
-                    # too), so each dead DELETE strands its own node.  `died`
-                    # is in scope here, so the static-CN scatter grouping always
-                    # applies — no extra sort on the recovery plane.
+                    # too), so each dead DELETE strands its own node.  Dead
+                    # lanes are not in valid_o, so plan_all's key runs cannot
+                    # group them: they pay their own (key, CN, pos) sort.
                     dead_upd = dead_w & (kinds == OpKind.UPDATE)
-                    dead_node = (_group_last(dead_upd)
+                    dead_node = (wc.local_executors(keys, cn, pos, dead_upd)
                                  | (dead_w & (kinds == OpKind.DELETE)))
                 else:
                     dead_node = dead_w

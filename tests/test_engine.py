@@ -2,6 +2,8 @@
 I/O metering formulas, and write-combining invariants (hypothesis-based)."""
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -229,3 +231,114 @@ def test_combine_plan_invariants():
         tail = members.max()
         assert bool(np.asarray(stats.is_tail)[tail])
         assert int(np.asarray(stats.mult_of)[tail]) == len(members)
+
+
+# ---------------------------------------------------------------------------
+# Local-WC grouping (step 4): the key-run cell table against the sort.
+# ---------------------------------------------------------------------------
+
+def _wc_window(seed, n_keys, n_cns, key_lo=0, b=B):
+    """A duplicate-heavy window: few keys, random CNs, a shuffled unique
+    ``pos`` and some invalid lanes."""
+    rng = np.random.default_rng(seed)
+    kinds = rng.choice([OpKind.SEARCH, OpKind.INSERT, OpKind.UPDATE,
+                        OpKind.DELETE, OpKind.NOP], size=b,
+                       p=(0.15, 0.1, 0.55, 0.1, 0.1)).astype(np.int32)
+    keys = rng.integers(key_lo, key_lo + n_keys, b).astype(np.int32)
+    batch = OpBatch(kinds=jnp.asarray(kinds), keys=jnp.asarray(keys),
+                    values=jnp.asarray(rng.integers(0, 10_000, b), jnp.int32),
+                    pos=jnp.asarray(rng.permutation(b), jnp.int32),
+                    cn=jnp.asarray(rng.integers(0, n_cns, b), jnp.int32))
+    valid = jnp.asarray(rng.random(b) < 0.85)
+    return batch, valid, rng
+
+
+def _last_per_group(keys, cn, pos, mask):
+    """Numpy reference: the max-``pos`` masked lane of each (key, cn)."""
+    out = np.zeros(mask.shape, bool)
+    for k, c in set(zip(keys[mask].tolist(), cn[mask].tolist())):
+        lanes = np.flatnonzero(mask & (keys == k) & (cn == c))
+        out[lanes[np.argmax(pos[lanes])]] = True
+    return out
+
+
+def _check_combine_grouping(seed, n_cns):
+    batch, valid, rng = _wc_window(seed, n_keys=6, n_cns=n_cns)
+    keys, cn, pos = batch.keys, batch.cn, batch.pos
+    run_id = wc.key_run_ids(wc.plan_combine(keys, pos, valid))
+    strict = valid & jnp.asarray(rng.random(B) < 0.5)
+    for mask in (valid, strict, jnp.zeros((B,), bool)):
+        got = wc.local_executors_by_run(run_id, cn, pos, mask, n_cns)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(wc.local_executors(keys, cn, pos, mask)))
+
+
+def _check_engine_grouping(mode, sharded):
+    """apply_batch with a liveness plane (the key-run cell table) equals
+    apply_batch without one (the (key, CN, pos) sort), result for result —
+    sharded with a nonzero base and half the window's keys out of shard."""
+    n_cns = 4
+    base = N_SLOTS if sharded else 0
+    batch, valid, _ = _wc_window(7, n_keys=2 * N_SLOTS if sharded else 8,
+                                 n_cns=n_cns)
+    cfg = _cfg(mode, n_slots=N_SLOTS)
+    kw = {}
+    if sharded:
+        kw = dict(owned=(batch.keys >= base) & (batch.keys < base + N_SLOTS),
+                  slot_base=jnp.int32(base))
+    outs = []
+    for plane in ({}, dict(alive=jnp.ones(n_cns, bool))):
+        state = populate(cfg, store_init(cfg), np.arange(0, N_SLOTS, 2),
+                         np.arange(N_SLOTS // 2))
+        outs.append(apply_batch(cfg, state, credit_init(64), batch,
+                                valid=valid, **kw, **plane))
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(np.asarray(a),
+                                                            np.asarray(b)),
+                 outs[0], outs[1])
+
+
+def _check_recovery_grouping(mode):
+    """Step 5b with a ``died`` plane: the dead CNs' UPDATEs strand one node
+    per (key, CN) local executor, dead DELETEs one each; live lockers repair
+    them (MCS counts the chain, SPIN one word per key)."""
+    n_cns = 4
+    batch, _, _ = _wc_window(11, n_keys=8, n_cns=n_cns)
+    died_np = np.array([False, True, False, True])
+    kinds, cn, pos = (np.asarray(batch.kinds), np.asarray(batch.cn),
+                      np.asarray(batch.pos))
+    # dead CNs write keys [4, 12): keys 8-11 have no live locker to repair them
+    keys = np.asarray(batch.keys) + 4 * died_np[cn]
+    batch = dataclasses.replace(batch, keys=jnp.asarray(keys, jnp.int32))
+    cfg = _cfg(mode, n_slots=N_SLOTS)
+    state, _, res, io = apply_batch(
+        cfg, store_init(cfg), credit_init(64), batch,
+        alive=jnp.asarray(~died_np), died=jnp.asarray(died_np))
+    upd, dele = kinds == OpKind.UPDATE, kinds == OpKind.DELETE
+    dead, live = died_np[cn], ~died_np[cn]
+    dead_node = _last_per_group(keys, cn, pos, dead & upd) | (dead & dele)
+    tot = np.bincount(keys[dead_node], minlength=N_SLOTS)
+    if mode == SyncMode.SPIN:
+        tot = np.minimum(tot, 1)
+    surv = _last_per_group(keys, cn, pos, live & upd) | (live & dele)
+    repaired = np.zeros(N_SLOTS, bool)
+    repaired[keys[surv]] = True
+    repaired &= tot > 0
+    np.testing.assert_array_equal(np.asarray(state.stranded),
+                                  np.where(repaired, 0, tot))
+    np.testing.assert_array_equal(np.asarray(res.orphan_wait),
+                                  np.where(surv & repaired[keys], tot[keys], 0))
+    assert int(io.orphan_windows) == int(((~repaired) & (tot > 0)).sum())
+    if mode == SyncMode.MCS:
+        assert int(io.repair_cas) == int(tot[repaired].sum())
+    assert int(tot.sum()) > int(tot[repaired].sum()) > 0   # both outcomes seen
+
+
+@pytest.mark.parametrize("case", [
+    ("combine", 0, 1), ("combine", 1, 4), ("combine", 2, 16), ("combine", 3, 4),
+    *[("engine", m, sharded) for m in MODES for sharded in (False, True)],
+    ("recovery", SyncMode.MCS), ("recovery", SyncMode.SPIN),
+], ids=str)
+def test_local_wc_grouping_matches_sort(case):
+    kind, *args = case
+    {"combine": _check_combine_grouping, "engine": _check_engine_grouping,
+     "recovery": _check_recovery_grouping}[kind](*args)

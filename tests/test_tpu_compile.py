@@ -98,3 +98,25 @@ def test_fused_scan_compiles_with_both_kernels(one_chip, monkeypatch):
                     if 'custom_call_target="tpu_custom_call"' in ln]
     assert any("jit(wc_combine)" in ln for ln in kernel_lines)
     assert any("jit(scan_probe)" in ln for ln in kernel_lines)
+
+
+def test_fused_scan_temp_is_window_sized(one_chip, monkeypatch):
+    """YCSB A's call program at the cell's shape (2^26 slots, B lanes,
+    16 CNs, CIDER, no SCAN) holds less than one byte of temporaries per
+    (slot, CN) pair, so no (slots x CNs) cell table of any element type
+    can come back: local WC groups by key run.  What remains (0.74 GB) is
+    step 5b's per-slot planes."""
+    monkeypatch.setattr(combine, "resolve_backend",
+                        lambda backend: ("pallas", False))
+    jax.clear_caches()
+    cfg = EngineConfig(n_slots=1 << 26, heap_slots=1 << 28,
+                       mode=SyncMode.CIDER, scan_max=0)
+    z = jnp.zeros((1, B), jnp.int32)
+    shapes = jax.eval_shape(lambda: (
+        engine.store_init(cfg), credit_init(4096),
+        runner.make_stream(z, z, z, n_cns=N_CNS)))
+    args = jax.tree.map(lambda s: _spec(s.shape, one_chip, s.dtype), shapes)
+    prev = _spec((N_CNS,), one_chip, jnp.bool_)
+    compiled = runner._scan_windows.lower(cfg, *args, prev, False,
+                                          False).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < cfg.n_slots * N_CNS
