@@ -73,6 +73,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -87,6 +88,8 @@ __all__ = ["StoreState", "Results", "store_init", "store_view", "apply_batch",
            "populate", "pack_meta"]
 
 _NONE = jnp.int32(-1)
+_BIG = 2**31 - 1   # sort key of an invalid lane: after every real slot
+_LANE_SHIFT = 4    # step 5c's sort payload: lane index above 4 flag bits
 
 
 _VER_MASK = jnp.int32(0xF)      # the 4-bit version field of Fig 8
@@ -214,6 +217,78 @@ def _probe_sweep(keys_sorted, setcode, writer, e_init, backend):
                              interpret=interpret)
     from repro.kernels.scan_probe.ref import scan_probe_ref
     return scan_probe_ref(keys_sorted, setcode, writer, e_init)
+
+
+def _run_exists(ptr, first, width):
+    """(R, width) bool: existence at window start of slots ``first[r] + j``,
+    read as whole rows of ``ptr`` rather than one scattered word per slot
+    (DESIGN.md §10.3).  ``ptr`` is viewed as rows of ``lanes`` words (128 on
+    the 2^k planes the store uses: a free reshape of the tiled plane, so
+    XLA emits one native row gather) and each run takes the ``k``
+    consecutive rows that cover it.  Rows near either end of the plane are
+    clamped; rotating each run's row block left by its offset puts every
+    in-range slot in its column.  Columns whose slot lies outside
+    ``[0, len(ptr))`` hold garbage."""
+    n = ptr.shape[0]
+    lanes = math.gcd(n, 128)
+    k = min(-(-(width - 1) // lanes) + 1, n // lanes)
+    r0 = jnp.clip(jnp.floor_divide(first, lanes), 0, n // lanes - k)
+    rows = ptr.reshape(n // lanes, lanes)[r0[:, None] + jnp.arange(k)]
+    m = max(k * lanes, width)
+    rows = jnp.pad((rows != NULL_PTR).reshape(-1, k * lanes),
+                   ((0, 0), (0, m - k * lanes)))
+    # an in-range slot sits at offset + j in [0, k*lanes), inside [0, m), so
+    # a rotation mod m is exact; done as log2(m) static rolls
+    shift = (first - r0 * lanes) % m
+    for bit in range((m - 1).bit_length()):
+        rows = jnp.where(((shift >> bit) & 1)[:, None] == 1,
+                         jnp.roll(rows, -(1 << bit), axis=1), rows)
+    return rows[:, :width]
+
+
+def _scan_probes(ptr, first, pos, valid, p_in, setcode, writer, backend):
+    """The fused probe pass of step 5c over B base lanes + B*ns probe lanes,
+    moved only by sorts (DESIGN.md §10.3).
+
+    Op ``i`` (slot ``first[i]`` of this shard's plane, queue position
+    ``pos[i]``) has base lane ``i`` — its own ``setcode`` transfer and
+    ``writer`` lock — and probe lanes ``(i, j)`` over slot ``first[i] + j``
+    at the same position, live where ``p_in``.  One sort of the combined
+    lanes by (slot, pos) carries each lane's flags packed beside its lane
+    index; ``scan_probe`` sweeps them; a second sort on the lane index brings
+    the answers back to op order.  Returns ``(e_before, waits)``, both
+    ``(B, ns)``: existence just before each probe and the exclusive holders
+    ahead of it in its slot's queue (meaningful where ``p_in``)."""
+    b, ns = p_in.shape
+    bc = b * (1 + ns)
+    if bc >= 1 << (31 - _LANE_SHIFT):
+        raise ValueError(f"{bc} SCAN lanes overflow the sort payload's "
+                         "lane index")
+    jj = jnp.arange(ns, dtype=jnp.int32)
+    slot_c = jnp.concatenate([first, (first[:, None] + jj).reshape(-1)])
+    pos_c = jnp.concatenate([pos, jnp.repeat(pos, ns)])
+    live = jnp.concatenate([valid, p_in.reshape(-1)])
+    # a probe's e_init is read in op order as its SCAN's slot row; a base
+    # lane's own e_before is never read, so it carries none
+    e_init = _run_exists(ptr, first, ns).reshape(-1)
+    flags = jnp.concatenate([(setcode + 1) | (writer.astype(jnp.int32) << 2),
+                             e_init.astype(jnp.int32) << 3])
+    payload = flags | (jnp.arange(bc, dtype=jnp.int32) << _LANE_SHIFT)
+    # the payload is the third key: a SCAN's base lane and its anchor probe
+    # tie on (slot, pos), and the lane index in its high bits keeps them in
+    # lane order as a stable sort would, without the index operand XLA adds
+    # to a stable sort
+    ks, _, pay = jax.lax.sort((jnp.where(live, slot_c, _BIG), pos_c, payload),
+                              num_keys=3, is_stable=False)
+    e_bc, waits_s = _probe_sweep(ks, (pay & 3) - 1, (pay & 4) != 0,
+                                 (pay & 8) != 0, backend)
+    # back to op order by the lane index, waits (< b) and e_before riding
+    # along in one word
+    ans = (waits_s << 1) | e_bc.astype(jnp.int32)
+    _, ans = jax.lax.sort((pay >> _LANE_SHIFT, ans), num_keys=1,
+                          is_stable=False)              # lanes are unique
+    ans = ans[b:].reshape(b, ns)
+    return (ans & 1) == 1, ans >> 1
 
 
 # ---------------------------------------------------------------------------
@@ -609,12 +684,11 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
     # queue *as a reader* at the scanning op's batch position.  ONE sort of
     # the combined writer+probe lanes feeds the fused scan_probe pass, which
     # yields both the existence each probe observes at its serialization
-    # position AND its wait rank behind exclusive lock holders — the second
-    # linearization sweep and the separate reader_waits sort this step used
-    # to pay are gone (DESIGN.md §10.3).  Probes outside [slot_base,
-    # slot_base + n_slots) belong to another shard (or fall off the keyspace
-    # end): each shard counts its own sub-run and the dist psum reassembles
-    # the rows.
+    # position AND its wait rank behind exclusive lock holders; the lanes
+    # move only by sorts (`_scan_probes`, DESIGN.md §10.3).  Probes
+    # outside [slot_base, slot_base + n_slots) belong to another shard (or
+    # fall off the keyspace end): each shard counts its own sub-run and the
+    # dist psum reassembles the rows.
     with jax.named_scope("engine.s5c_scan"):
         if cfg.scan_max > 0:
             ns = cfg.scan_max
@@ -626,32 +700,17 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
             p_in = (is_scan[:, None] & (jj[None, :] < count[:, None])
                     & (p_loc >= 0) & (p_loc < cfg.n_slots))
             keys_p = pk.reshape(b * ns)
-            pos_p = jnp.broadcast_to(pos[:, None], (b, ns)).reshape(b * ns)
             pv = p_in.reshape(b * ns)
-            keys_c = jnp.concatenate([keys, keys_p])
-            pos_c = jnp.concatenate([pos, pos_p])
-            tvalid = jnp.concatenate([valid_o, pv])
-            plan_c = wc.plan_combine(keys_c, pos_c, tvalid,
-                                     backend=cfg.kernel_backend)
-            pc = plan_c.perm
-            bc = b * (1 + ns)
             # existence transfers: only this window's INSERT/DELETE lanes set the
             # bit; probes (readers) and UPDATEs are identity — so the probe pass
             # needs no value plane at all
-            setcode_c = jnp.concatenate(
-                [jnp.where(is_insert, jnp.int32(1),
-                           jnp.where(is_delete, jnp.int32(0), jnp.int32(-1))),
-                 jnp.full((b * ns,), -1, jnp.int32)])
+            setcode = jnp.where(is_insert, jnp.int32(1),
+                                jnp.where(is_delete, jnp.int32(0), jnp.int32(-1)))
             lockw = loc_exec_pess | is_delete             # exclusive lock holders
-            writer_c = jnp.concatenate([lockw, jnp.zeros((b * ns,), bool)])
-            slot_c = jnp.clip(keys_c[pc] - base, 0, cfg.n_slots - 1)
-            e_init_c = state.ptr[slot_c] != NULL_PTR
-            e_bc, waits_s = _probe_sweep(plan_c.keys_sorted, setcode_c[pc],
-                                         writer_c[pc], e_init_c,
-                                         cfg.kernel_backend)
-            v_sc = tvalid[pc]
-            e_probe = jnp.zeros((bc,), bool).at[pc].set(e_bc & v_sc)
-            hit = e_probe[b:].reshape(b, ns) & p_in
+            e_probe, waits_p = _scan_probes(
+                state.ptr, keys - base, pos, valid_o, p_in, setcode, lockw,
+                cfg.kernel_backend)
+            hit = e_probe & p_in
             per_op_rows = jnp.sum(hit.astype(jnp.int32), axis=1)
             n_probes = s(pv)
             n_rows = s(hit)
@@ -691,12 +750,8 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
             if cfg.mode != SyncMode.OSYNC:
                 # wait rank of the anchor-leaf reader behind exclusive holders
                 # (queue order == batch position now includes reader ranks) —
-                # already computed by the fused pass above; just unsort it
-                readers_s = jnp.concatenate([jnp.zeros((b,), bool), pv])[pc]
-                waits = jnp.zeros((bc,), jnp.int32).at[pc].set(
-                    jnp.where(readers_s, waits_s, 0))
-                per_op_rank = jnp.where(p_in[:, 0], waits[b:].reshape(b, ns)[:, 0],
-                                        per_op_rank)
+                # already computed by the fused pass above
+                per_op_rank = jnp.where(p_in[:, 0], waits_p[:, 0], per_op_rank)
 
     # ---- 5d. SNAPSHOT replica fan-out (FUSEE; DESIGN.md §13) --------------
     # Client-centric replication: the client issues every write-class verb

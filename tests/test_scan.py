@@ -296,3 +296,181 @@ def test_modeled_latency_scan_orderings():
     assert p99[SyncMode.CIDER] < p99[SyncMode.OSYNC]
     assert p99[SyncMode.CIDER] < p99[SyncMode.SPIN]
     assert p99[SyncMode.CIDER] < p99[SyncMode.MCS]
+
+
+# ---------------------------------------------------------------------------
+# Step 5c's data movement: the sorts that carry the probe lanes against the
+# formulation they replaced (one lexsort, gathers into sorted order, a gather
+# of `ptr` per sorted lane, scatters back), kept here as the oracle.
+# ---------------------------------------------------------------------------
+
+def _scan_probes_by_gathers(ptr, first, pos, valid, p_in, setcode, writer,
+                            backend):
+    """``engine._scan_probes``'s contract, computed as step 5c did before the
+    sorts carried the lanes: ``plan_combine``'s lexsort, the lanes and the
+    ``ptr`` words gathered into sorted order, the answers scattered back."""
+    import jax.numpy as jnp
+
+    from repro.core import combine as wc
+    from repro.core import engine
+    from repro.core.types import NULL_PTR
+    b, ns = p_in.shape
+    bc = b * (1 + ns)
+    jj = jnp.arange(ns, dtype=jnp.int32)
+    keys_c = jnp.concatenate([first, (first[:, None] + jj).reshape(-1)])
+    pos_c = jnp.concatenate([pos, jnp.repeat(pos, ns)])
+    tvalid = jnp.concatenate([valid, p_in.reshape(-1)])
+    plan = wc.plan_combine(keys_c, pos_c, tvalid, backend=backend)
+    pc = plan.perm
+    setcode_c = jnp.concatenate([setcode, jnp.full((b * ns,), -1, jnp.int32)])
+    writer_c = jnp.concatenate([writer, jnp.zeros((b * ns,), bool)])
+    slot_c = jnp.clip(keys_c[pc], 0, ptr.shape[0] - 1)
+    e_init = ptr[slot_c] != NULL_PTR
+    e_bc, waits_s = engine._probe_sweep(plan.keys_sorted, setcode_c[pc],
+                                        writer_c[pc], e_init, backend)
+    e_probe = jnp.zeros((bc,), bool).at[pc].set(e_bc & tvalid[pc])
+    readers = jnp.concatenate([jnp.zeros((b,), bool), p_in.reshape(-1)])[pc]
+    waits = jnp.zeros((bc,), jnp.int32).at[pc].set(
+        jnp.where(readers, waits_s, 0))
+    return e_probe[b:].reshape(b, ns), waits[b:].reshape(b, ns)
+
+
+def _edge_scan_ops():
+    """(W, B) windows for the probe-pass comparison: a strided cross-CN hot
+    key (CIDER goes pessimistic from window 1), and pinned lanes giving
+    every case the sorts must keep — counts 0, 1 and SCAN_MAX; inserts and
+    deletes inside a scan's run both before and after its position; runs
+    that start within SCAN_MAX of either end of a shard's slot range
+    (shards of 16 on the 4-way mesh) and of the keyspace."""
+    kinds, keys, values = _scan_ops(seed=7)
+    pins = [  # (lane, kind, key, value)
+        (2, OpKind.DELETE, 20, 0), (3, OpKind.INSERT, 22, 11),
+        (9, OpKind.SCAN, 18, SCAN_MAX),        # run 18-25, after lanes 2, 3
+        (10, OpKind.INSERT, 19, 12), (11, OpKind.DELETE, 21, 0),
+        (13, OpKind.SCAN, 30, SCAN_MAX),       # crosses the 31/32 boundary
+        (17, OpKind.SCAN, 45, 1), (18, OpKind.SCAN, 46, 0),
+        (21, OpKind.SCAN, 60, SCAN_MAX),       # falls off the keyspace end
+        (22, OpKind.SCAN, 0, SCAN_MAX), (23, OpKind.SCAN, 9, SCAN_MAX),
+        (25, OpKind.DELETE, 47, 0), (26, OpKind.SCAN, 44, SCAN_MAX),
+        (27, OpKind.INSERT, 49, 13), (29, OpKind.SCAN, 63, 3),
+    ]
+    for lane, kind, key, value in pins:
+        kinds[:, lane], keys[:, lane], values[:, lane] = kind, key, value
+    return kinds, keys, values
+
+
+@pytest.fixture
+def gathers_oracle(monkeypatch):
+    """Run the engine with step 5c's old data movement; the jit caches are
+    cleared on both sides so no trace of either formulation is reused."""
+    import jax
+
+    from repro.core import engine
+    jax.clear_caches()
+    monkeypatch.setattr(engine, "_scan_probes", _scan_probes_by_gathers)
+    yield
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+def _run_edge_stream(cfg, shards):
+    kinds, keys, values = _edge_scan_ops()
+    st, cr, pop_keys, pop_vals = _init(cfg)
+    stream = runner.make_stream(kinds, keys, values, n_cns=N_CNS)
+    if shards == 1:
+        st, cr, res, io = runner.run_windows(cfg, st, cr, stream,
+                                             io_per_window=True)
+        view = store_view(st)
+    else:
+        sst = dstore.sharded_populate(
+            cfg, shards, dstore.sharded_store_init(cfg, shards), pop_keys,
+            pop_vals)
+        st, cr, res, io = dstore.run_windows_sharded(
+            cfg, make_local_mesh(data=shards), sst, credit_init(256), stream,
+            io_per_window=True)
+        view = dstore.sharded_store_view(cfg, shards, st)
+    out = {f"Results.{f.name}": getattr(res, f.name)
+           for f in dataclasses.fields(res)}
+    out.update({f"IOMetrics.{f.name}": getattr(io, f.name)
+                for f in dataclasses.fields(IOMetrics)})
+    out.update({"view.exists": view[0], "view.value": view[1],
+                "credit": cr.credit})
+    return {k: np.asarray(v) for k, v in out.items()}
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("mode", MODES)
+def test_scan_probe_sorts_match_the_gather_formulation(mode, backend, shards,
+                                                       request):
+    """Step 5c moves its probe lanes by two sorts instead of permutation
+    gathers and scatters: rows (per-op hits), ranks (the anchor reader's
+    wait), the 11-field bill, every other result, the store and the credit
+    table are bit-equal to the gather formulation, on one device and on
+    the 4-way mesh, for both kernel backends."""
+    cfg = EngineConfig(n_slots=N_SLOTS, heap_slots=2048, mode=mode,
+                       scan_max=SCAN_MAX, kernel_backend=backend)
+    got = _run_edge_stream(cfg, shards)
+    request.getfixturevalue("gathers_oracle")
+    want = _run_edge_stream(cfg, shards)
+    assert got["Results.rows"].sum() > 0
+    if mode != SyncMode.OSYNC:
+        # some anchor reader waits behind an exclusive holder
+        assert (got["Results.rank"][got["Results.rows"] > 0] > 0).any()
+    assert len(got) == len(want) == 9 + 11 + 3
+    for name in want:
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("mode", MODES)
+def test_scan_step_moves_probe_lanes_only_by_sorts(mode, backend):
+    """Step 5c's jaxpr (B = 16, scan_max = 7) holds no gather or scatter
+    whose operand or index spans the B*scan_max probe lanes or the
+    B*(1+scan_max) combined lanes, but CIDER's credit-table lookup, and at
+    most two sorts of that size: the permutations the sorts replaced
+    cannot come back unseen."""
+    import math
+
+    import jax
+
+    from repro.analysis.jaxpr_check import (_as_jaxpr, _cfg, _engine_args,
+                                            _sub_jaxprs)
+    from repro.core import engine
+    cfg = _cfg(mode, backend, scan_max=7)
+    st, _, batch, valid, alive, died = _engine_args(cfg)
+    n_credit = 256                    # apart from the 64-slot `ptr` plane
+    closed = jax.make_jaxpr(lambda *a: engine.apply_batch(
+        cfg, *a[:3], valid=a[3], alive=a[4], died=a[5]))(
+        st, credit_init(n_credit), batch, valid, alive, died)
+    b, ns = batch.keys.shape[0], cfg.scan_max
+    assert (b, ns) == (16, 7)
+    lanes = {b * ns, b * (1 + ns)}
+
+    def size(v):
+        return math.prod(v.aval.shape)
+
+    moves, sorts, in_scope = [], 0, 0
+    stack = [(_as_jaxpr(closed), False)]
+    while stack:
+        jaxpr, inside = stack.pop()
+        for eqn in jaxpr.eqns:
+            here = inside or "engine.s5c_scan" in str(
+                eqn.source_info.name_stack)
+            for sub in _sub_jaxprs(eqn):
+                stack.append((sub, here))
+            if not here:
+                continue
+            in_scope += 1
+            name = eqn.primitive.name
+            if name == "sort" and size(eqn.invars[0]) in lanes:
+                sorts += 1
+            if name == "gather" or name.startswith("scatter"):
+                operand, index = eqn.invars[0], eqn.invars[1]
+                if size(operand) in lanes or size(index) in lanes:
+                    moves.append((name, operand.aval.shape, index.aval.shape))
+    assert in_scope > 20
+    credit = [m for m in moves if m[0] == "gather" and m[1] == (n_credit,)]
+    assert len(credit) == (mode == SyncMode.CIDER)
+    assert [m for m in moves if m not in credit] == []
+    assert 1 <= sorts <= 2
