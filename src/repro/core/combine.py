@@ -17,8 +17,7 @@ path below — bit-identical by contract and by test.
 
 DESIGN.md §2.1 (the combine primitive): one lexsort materializes every wait
 queue; reader ranks extend it to SCAN (§9.2); §10 covers backend dispatch
-and the shared-sort derived plans (``stats_from_plan``, ``plan_groups``,
-``key_run_ids``).
+and the shared-sort derived plans (``stats_from_plan``, ``key_run_ids``).
 """
 from __future__ import annotations
 
@@ -27,10 +26,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-__all__ = ["CombinePlan", "plan_combine", "segment_last", "segment_counts",
-           "OpStats", "per_key_stats", "stats_from_plan", "GroupPlan",
-           "plan_groups", "group_last", "local_executors", "key_run_ids",
-           "local_executors_by_run", "reader_waits", "resolve_backend"]
+__all__ = ["CombinePlan", "plan_combine", "OpStats", "per_key_stats",
+           "stats_from_plan", "key_run_ids", "local_executors_by_run",
+           "resolve_backend"]
 
 _BIG = 2**31 - 1   # python int, weak-typed to int32 at use sites
 
@@ -118,25 +116,6 @@ def plan_combine(keys: jax.Array, pos: jax.Array, valid: jax.Array,
     )
 
 
-def segment_last(plan: CombinePlan, values: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Last-writer-wins combine: one (key, value) per wait queue.
-
-    Returns (unique_keys, winning_values, winner_mask_sorted); the first two
-    are length-B with garbage beyond ``plan.n_unique`` positions — callers
-    scatter with the mask, so no compaction is required on device.
-    """
-    vs = values[plan.perm]
-    return (jnp.where(plan.is_last, plan.keys_sorted, 0),
-            jnp.where(plan.is_last, vs, 0),
-            plan.is_last)
-
-
-def segment_counts(plan: CombinePlan, valid: jax.Array) -> jax.Array:
-    """Per-original-op queue length (the paper's "WC batch size"), unsorted order."""
-    out = jnp.zeros_like(plan.run_length)
-    return out.at[plan.perm].set(jnp.where(valid[plan.perm], plan.run_length, 0))
-
-
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class OpStats:
@@ -183,52 +162,6 @@ def per_key_stats(keys: jax.Array, pos: jax.Array, mask: jax.Array,
                            mask)
 
 
-@jax.tree_util.register_dataclass
-@dataclasses.dataclass
-class GroupPlan:
-    """(key, compute-node) group structure of one window, in sorted order.
-
-    ``perm`` sorts by (key, cn, pos); ``g_end`` is the sorted index of my
-    group's last element.  One such sort serves every masked subset via
-    ``group_last`` (DESIGN.md §10.2).
-    """
-    perm: jax.Array    # (B,) int32
-    g_end: jax.Array   # (B,) int32
-
-
-def plan_groups(keys: jax.Array, cn: jax.Array, pos: jax.Array,
-                valid: jax.Array) -> GroupPlan:
-    """Sort once by (key, cn, pos); invalid lanes form a +inf tail run."""
-    k = jnp.where(valid, keys, _BIG)
-    order = jnp.lexsort((pos, cn, k))
-    ks, cs = k[order], cn[order]
-    glast = jnp.concatenate([(ks[1:] != ks[:-1]) | (cs[1:] != cs[:-1]),
-                             jnp.ones((1,), bool)])
-    idx = jnp.arange(k.shape[0], dtype=jnp.int32)
-    g_end = jax.lax.cummin(jnp.where(glast, idx, _BIG), reverse=True)
-    return GroupPlan(perm=order.astype(jnp.int32), g_end=g_end)
-
-
-def group_last(gplan: GroupPlan, mask: jax.Array) -> jax.Array:
-    """Last masked lane of each (key, cn) group, unsorted order.
-
-    Precondition: ``mask ⊆`` the validity ``plan_groups`` was built with.
-    Equals ``local_executors(keys, cn, pos, mask)`` bit-for-bit (stable
-    sort: masked lanes keep their relative order inside each group).
-    """
-    mask_s = mask[gplan.perm]
-    c = jnp.cumsum(mask_s.astype(jnp.int32))
-    is_lastm_s = mask_s & (c == c[gplan.g_end])
-    return jnp.zeros(mask.shape, bool).at[gplan.perm].set(is_lastm_s)
-
-
-def local_executors(keys: jax.Array, cn: jax.Array, pos: jax.Array,
-                    mask: jax.Array) -> jax.Array:
-    """Local write combining (§3.1): the last (by ``pos``) masked op of each
-    (key, compute-node) group — the only one that leaves the CN."""
-    return group_last(plan_groups(keys, cn, pos, mask), mask)
-
-
 def key_run_ids(plan: CombinePlan) -> jax.Array:
     """Per-lane key-run id in batch order: the sorted index of the first
     lane of the lane's ``plan`` run, in ``[0, B)``.  Two lanes of the plan's
@@ -241,52 +174,20 @@ def key_run_ids(plan: CombinePlan) -> jax.Array:
 
 def local_executors_by_run(run_id: jax.Array, cn: jax.Array, pos: jax.Array,
                            mask: jax.Array, n_cns: int) -> jax.Array:
-    """Sort-free ``local_executors``: one O(B) scatter-max over a static
-    ``(B * n_cns,)`` cell table keyed by (key run, cn) instead of a
-    (key, cn, pos) lexsort.
+    """Local write combining (§3.1): the last (by ``pos``) masked op of each
+    (key, compute-node) group — the only one that leaves the CN — found by
+    one O(B) scatter-max over a static ``(B * n_cns,)`` cell table keyed by
+    (key run, cn), with no sort of its own.
 
     ``run_id`` comes from ``key_run_ids`` of a plan whose validity covers
     ``mask``, so masked lanes share a cell iff they share (key, cn); the
     table's size depends on the window and the CN count, never on the
-    store.  Bit-identical to ``local_executors`` under the ``OpBatch``
-    contract the engine already relies on — ``pos`` unique per batch
+    store.  Exact under the ``OpBatch`` contract: ``pos`` unique per batch
     (serialization priorities 0..B-1) and ``cn ∈ [0, n_cns)``
     (``OpBatch.make`` takes cn mod n_cns; the liveness plane clips the same
-    way): the unique max-pos masked lane of each cell IS the stable sort's
-    group tail.  DESIGN.md §10.2.
+    way), so each cell's max-pos masked lane is unique.  DESIGN.md §10.2.
     """
     gi = run_id * n_cns + jnp.clip(cn, 0, n_cns - 1)
     buf = jnp.full((run_id.shape[0] * n_cns,), -1, jnp.int32)
     buf = buf.at[gi].max(jnp.where(mask, pos, -1))
     return mask & (buf[gi] == pos)
-
-
-def reader_waits(keys: jax.Array, pos: jax.Array, readers: jax.Array,
-                 writers: jax.Array) -> jax.Array:
-    """Per-reader count of lock-holding writers *ahead* of it in its queue.
-
-    SCAN support (DESIGN.md §9): a reader joins the per-key wait queue at its
-    op's batch position, so the number of masked ``writers`` on the same key
-    with a strictly smaller ``pos`` is exactly how many exclusive holders the
-    reader sits behind.  Precondition: no reader shares a (key, pos) pair
-    with a writer (readers inherit their parent op's position; a lane is
-    either a reader probe or a writer, never both on one slot).
-
-    This standalone form pays its own lexsort; the engine's SCAN path fuses
-    the same computation into the ``scan_probe`` kernel pass over the
-    already-sorted probe lanes (DESIGN.md §10.3).
-
-    Returns (N,) int32 — the wait rank for reader lanes, 0 elsewhere.
-    """
-    n = keys.shape[0]
-    mask = readers | writers
-    k = jnp.where(mask, keys, _BIG)
-    order = jnp.lexsort((pos, k))
-    ks = k[order]
-    w_s = (writers & mask)[order].astype(jnp.int32)
-    is_first = jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]])
-    excl = jnp.cumsum(w_s) - w_s                   # writers before me, globally
-    base = jax.lax.cummax(jnp.where(is_first, excl, 0))
-    waits_s = excl - base                          # writers before me, in-queue
-    out = jnp.zeros((n,), jnp.int32)
-    return out.at[order].set(jnp.where(readers[order], waits_s, 0))

@@ -355,6 +355,11 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
         # present: ops issued into this window (including ones whose CN crashes
         # mid-window — the orphan candidates); valid: ops that complete.
         present = valid
+        for name, plane in (("alive", alive), ("died", died)):
+            if plane is not None and jnp.shape(plane) != (batch.n_cns,):
+                raise ValueError(
+                    f"{name} is {jnp.shape(plane)}, expected ({batch.n_cns},): "
+                    "one bit per compute node of the batch")
         if alive is not None:
             a = jnp.asarray(alive, bool)
             valid = valid & a[jnp.clip(cn, 0, a.shape[0] - 1)]
@@ -370,7 +375,13 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
 
         # ---- 1. linearize: one sorted last-setter sweep serializes every
         # slot's queue (DESIGN.md §10.2) ----
-        plan_all = wc.plan_combine(keys, pos, valid_o, backend=cfg.kernel_backend)
+        # The window's ONE (key, pos) plan covers every issued lane: the
+        # lanes of other shards form key runs of their own (keys are range
+        # partitioned) and a dying CN's lanes sit inside the key runs they
+        # strand, so every masked question below — data plane, crash plane,
+        # full-window credit feedback — is a mask over this plan.  Data-plane
+        # reads mask with v_sorted.
+        plan_all = wc.plan_combine(keys, pos, present, backend=cfg.kernel_backend)
         perm = plan_all.perm
         idx = jnp.arange(b, dtype=jnp.int32)
         seg_start = idx - plan_all.rank
@@ -420,7 +431,10 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
     # ---- 2. commit final slot states (one out-of-place write per queue) ----
     # Out-of-bounds indices with mode="drop" mask out non-committing lanes.
     with jax.named_scope("engine.s2_commit"):
-        tail = plan_all.is_last & seg_any_write & v_sorted
+        # a run commits at its last completing lane, which lanes of a CN
+        # that dies this window may follow
+        cv = jnp.cumsum(v_sorted.astype(jnp.int32))
+        tail = v_sorted & (cv == cv[seg_end]) & seg_any_write
         oob_h, oob_s = jnp.int32(cfg.heap_slots), jnp.int32(cfg.n_slots)
         n_commits = jnp.sum(tail.astype(jnp.int32))
         commit_rank = jnp.cumsum(tail.astype(jnp.int32)) - 1
@@ -433,7 +447,7 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
         ds_i = del_succ.astype(jnp.int32)
         cd = jnp.cumsum(ds_i)
         run_del = cd[seg_end] - (cd - ds_i)[seg_start]
-        ver = (state.ver.at[jnp.where(plan_all.is_last, slot, oob_s)]
+        ver = (state.ver.at[jnp.where(tail, slot, oob_s)]
                .add(run_del, mode="drop")) % 16
 
     # ---- 3. synchronization-mode decision (CIDER credit split, §4.3) ----
@@ -455,36 +469,24 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
     # combined ops never leave the CN.  CIDER's pessimistic path does NOT
     # pre-filter: every client enqueues in the *global* MCS queue (Fig 7),
     # and global WC subsumes local WC.
-    # Two bit-identical groupings (DESIGN.md §10.2): with a static CN count
-    # in scope (the liveness plane's shape — always true on the fused
-    # runner/bench path) each masked subset costs one O(B) scatter-max over a
-    # (B * n_cns) cell table keyed by the lane's key run in plan_all;
-    # otherwise one (key, CN, pos) sort over the owned window serves every
-    # subset.  Both executor masks are subsets of valid_o, so either matches
-    # a dedicated local_executors sort per mask.
+    # Every grouped mask (here, step 5b's dead UPDATEs, step 6's full-window
+    # optimistic writers) is a subset of `present`, so a lane's (key, CN)
+    # group is its key run in plan_all times its CN: one O(B) scatter-max
+    # over a (B * n_cns) cell table per mask, no sort (DESIGN.md §10.2).
     with jax.named_scope("engine.s4_local_wc"):
-        n_cns_static = None
-        for liveness in (alive, died):
-            if liveness is not None:
-                n_cns_static = jnp.asarray(liveness).shape[0]
-                break
-        if n_cns_static is not None:
-            run_id = wc.key_run_ids(plan_all) if cfg.local_wc else None
+        run_id = wc.key_run_ids(plan_all) if cfg.local_wc else None
 
-            def _group_last(mask):
-                return wc.local_executors_by_run(run_id, cn, pos, mask,
-                                                 n_cns_static)
-        else:
-            gplan = wc.plan_groups(keys, cn, pos, valid_o) if cfg.local_wc else None
-
-            def _group_last(mask):
-                return wc.group_last(gplan, mask)
-        loc_exec_opt = _group_last(opt_upd) if cfg.local_wc else opt_upd
-        if cfg.mode in (SyncMode.CIDER, SyncMode.OSYNC) or not cfg.local_wc:
+        def _local_exec(mask):
+            if not cfg.local_wc:
+                return mask
+            return wc.local_executors_by_run(run_id, cn, pos, mask,
+                                             batch.n_cns)
+        loc_exec_opt = _local_exec(opt_upd)
+        if cfg.mode in (SyncMode.CIDER, SyncMode.OSYNC):
             # CIDER: global WC subsumes local; OSYNC: pess is statically empty
             loc_exec_pess = pess
         else:
-            loc_exec_pess = _group_last(pess)
+            loc_exec_pess = _local_exec(pess)
 
     # ---- 5. per-mode I/O metering ------------------------------------------
     with jax.named_scope("engine.s5_meter"):
@@ -535,7 +537,7 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
             opt_queue = loc_exec_opt
         # opt_queue/loc_exec_pess ⊆ valid_o, so their queue statistics fall out
         # of plan_all's existing sort (stats_from_plan, DESIGN.md §10.2) —
-        # bit-identical to per_key_stats, minus the extra lexsorts.
+        # bit-identical to per_key_stats, minus a sort per mask.
         plan_o = wc.stats_from_plan(plan_all, opt_queue)
         m_opt_writes = s(loc_exec_opt)                   # DELETEs write no heap
         writes += m_opt_writes
@@ -623,18 +625,13 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
                     cslot = credit_slot(keys, credits.credit.shape[0])
                     dead_node = dead_w & ((credits.credit[cslot] > 0)
                                           | (kinds == OpKind.DELETE))
-                elif cfg.local_wc:
+                else:
                     # only the local executor of each (key, CN) UPDATE group had
                     # left the crashed CN for the memory pool; DELETEs are never
                     # locally combined (they lock independently on the live path
-                    # too), so each dead DELETE strands its own node.  Dead
-                    # lanes are not in valid_o, so plan_all's key runs cannot
-                    # group them: they pay their own (key, CN, pos) sort.
-                    dead_upd = dead_w & (kinds == OpKind.UPDATE)
-                    dead_node = (wc.local_executors(keys, cn, pos, dead_upd)
+                    # too), so each dead DELETE strands its own node
+                    dead_node = (_local_exec(dead_w & (kinds == OpKind.UPDATE))
                                  | (dead_w & (kinds == OpKind.DELETE)))
-                else:
-                    dead_node = dead_w
                 # stranded-node count per slot: dead nodes are keyed by slot
                 # already, so the per-key multiplicity-at-tail reduction the
                 # sort-based stats would compute IS this one scatter-add
@@ -773,27 +770,18 @@ def apply_batch(cfg: EngineConfig, state: StoreState, credits: CreditState,
 
     # ---- 6. credit feedback (§4.3, Algorithm 1 lines 13-22) ---------------
     # Like the decision, feedback runs on the FULL window so replicated
-    # credit tables stay identical across shards; when unsharded the full
-    # masks ARE the owned masks and nothing is recomputed.
+    # credit tables stay identical across shards.  The full-window masks are
+    # subsets of `present`, so plan_all and its key runs answer them; on one
+    # chip they ARE step 5's owned masks and step 5's values are reused.
     with jax.named_scope("engine.s6_feedback"):
         if cfg.mode == SyncMode.CIDER:
             if owned is None:
                 pess_fb, batch_fb = loc_exec_pess, per_op_batch
                 opt_fb, retry_fb = loc_exec_opt | is_insert, per_op_retries
             else:
-                # full-window masks are subsets of `valid` (not valid_o), so the
-                # replicated credit plane pays one full-validity plan of each
-                # kind and derives both feedback stats from it (DESIGN.md §10.2)
-                opt_upd_full = upd_full & ~pess_full
-                if cfg.local_wc:
-                    gplan_full = wc.plan_groups(keys, cn, pos, valid)
-                    loc_opt_full = wc.group_last(gplan_full, opt_upd_full)
-                else:
-                    loc_opt_full = opt_upd_full
-                plan_full = wc.plan_combine(keys, pos, valid,
-                                            backend=cfg.kernel_backend)
-                plan_p_fb = wc.stats_from_plan(plan_full, pess_full)
-                plan_o_fb = wc.stats_from_plan(plan_full, loc_opt_full)
+                loc_opt_full = _local_exec(upd_full & ~pess_full)
+                plan_p_fb = wc.stats_from_plan(plan_all, pess_full)
+                plan_o_fb = wc.stats_from_plan(plan_all, loc_opt_full)
                 pess_fb = pess_full
                 batch_fb = jnp.where(pess_full, plan_p_fb.mult_of, 1)
                 opt_fb = loc_opt_full | ((kinds == OpKind.INSERT) & valid)

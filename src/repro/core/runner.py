@@ -118,7 +118,8 @@ def make_stream(kinds, keys, values, n_cns: int = 1,
                 f"alive is {alive.shape}, expected ({w}, {max(n_cns, 1)}) — "
                 f"the liveness schedule must match the stream's windows AND "
                 f"its CN count (a mismatch would silently mis-drop ops)")
-    batch = OpBatch(kinds=kinds, keys=keys, values=values, pos=pos, cn=cn)
+    batch = OpBatch(kinds=kinds, keys=keys, values=values, pos=pos, cn=cn,
+                    n_cns=max(n_cns, 1))
     return WindowStream(batch=batch, valid=jnp.asarray(valid, bool),
                         alive=alive)
 

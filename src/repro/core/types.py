@@ -197,13 +197,16 @@ class OpBatch:
     own I/O.  ``pos`` is the canonical serialization priority inside the batch
     (queue order == batch position, so all four modes agree on the final
     state: last-writer-wins by ``pos``).  ``cn`` is the compute-node id of the
-    issuing client (local-WC combines within a CN; global WC across CNs).
+    issuing client (local-WC combines within a CN; global WC across CNs), in
+    ``[0, n_cns)``; ``n_cns`` is static, since it sizes local WC's cell table
+    and the liveness planes.
     """
     kinds: jax.Array    # (B,) int32 OpKind
     keys: jax.Array     # (B,) int32 slot index
     values: jax.Array   # (B,) int32 value payload id
     pos: jax.Array      # (B,) int32 serialization priority (0..B-1)
     cn: jax.Array       # (B,) int32 compute-node id
+    n_cns: int = dataclasses.field(metadata={"static": True})
 
     @staticmethod
     def make(kinds, keys, values, n_cns: int = 1, lanes_per_cn: int | None = None):
@@ -212,10 +215,12 @@ class OpBatch:
         values = jnp.asarray(values, jnp.int32)
         b = kinds.shape[0]
         pos = jnp.arange(b, dtype=jnp.int32)
+        n_cns = max(n_cns, 1)
         if lanes_per_cn is None:
-            lanes_per_cn = max(b // max(n_cns, 1), 1)
-        cn = (pos // lanes_per_cn) % max(n_cns, 1)
-        return OpBatch(kinds=kinds, keys=keys, values=values, pos=pos, cn=cn)
+            lanes_per_cn = max(b // n_cns, 1)
+        cn = (pos // lanes_per_cn) % n_cns
+        return OpBatch(kinds=kinds, keys=keys, values=values, pos=pos, cn=cn,
+                       n_cns=n_cns)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,8 +1,8 @@
 """Fused SCAN reader-probe Pallas kernel: one VMEM pass over lanes SORTED
 by (key, pos) emitting, per lane, the existence bit observed just before it
 (``e_before``) and the count of writer lanes strictly ahead in its key run
-(``waits``) — the engine's step-5c probe resolution and ``reader_waits``
-rank in a single sweep (DESIGN.md §10.3), replacing two full sorts.
+(``waits``) — the engine's step-5c probe resolution and its readers' wait
+ranks in a single sweep (DESIGN.md §10.3), replacing two full sorts.
 
 Layout and cross-block carry follow wc_combine (DESIGN.md §2.1): the lanes
 are swept as tile-aligned ``(rows, lanes)`` int32 blocks on a sequential

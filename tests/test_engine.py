@@ -234,7 +234,7 @@ def test_combine_plan_invariants():
 
 
 # ---------------------------------------------------------------------------
-# Local-WC grouping (step 4): the key-run cell table against the sort.
+# Local-WC grouping (step 4): the key-run cell table against numpy.
 # ---------------------------------------------------------------------------
 
 def _wc_window(seed, n_keys, n_cns, key_lo=0, b=B):
@@ -248,7 +248,8 @@ def _wc_window(seed, n_keys, n_cns, key_lo=0, b=B):
     batch = OpBatch(kinds=jnp.asarray(kinds), keys=jnp.asarray(keys),
                     values=jnp.asarray(rng.integers(0, 10_000, b), jnp.int32),
                     pos=jnp.asarray(rng.permutation(b), jnp.int32),
-                    cn=jnp.asarray(rng.integers(0, n_cns, b), jnp.int32))
+                    cn=jnp.asarray(rng.integers(0, n_cns, b), jnp.int32),
+                    n_cns=n_cns)
     valid = jnp.asarray(rng.random(b) < 0.85)
     return batch, valid, rng
 
@@ -262,6 +263,12 @@ def _last_per_group(keys, cn, pos, mask):
     return out
 
 
+def _queue_rank(keys, pos, mask):
+    """Numpy reference: masked lanes ahead of each masked lane on its key."""
+    return np.array([int((mask & (keys == keys[i]) & (pos < pos[i])).sum())
+                     if mask[i] else 0 for i in range(mask.shape[0])])
+
+
 def _check_combine_grouping(seed, n_cns):
     batch, valid, rng = _wc_window(seed, n_keys=6, n_cns=n_cns)
     keys, cn, pos = batch.keys, batch.cn, batch.pos
@@ -270,13 +277,15 @@ def _check_combine_grouping(seed, n_cns):
     for mask in (valid, strict, jnp.zeros((B,), bool)):
         got = wc.local_executors_by_run(run_id, cn, pos, mask, n_cns)
         np.testing.assert_array_equal(
-            np.asarray(got), np.asarray(wc.local_executors(keys, cn, pos, mask)))
+            np.asarray(got), _last_per_group(*map(np.asarray, (keys, cn, pos,
+                                                                mask))))
 
 
 def _check_engine_grouping(mode, sharded):
-    """apply_batch with a liveness plane (the key-run cell table) equals
-    apply_batch without one (the (key, CN, pos) sort), result for result —
-    sharded with a nonzero base and half the window's keys out of shard."""
+    """apply_batch's local WC against numpy: which lanes ``Results.combined``
+    marks, the writes bill and every queue rank — sharded with a nonzero
+    base and half the window's keys out of shard, where CIDER's
+    full-window credit feedback must also match the unsharded engine's."""
     n_cns = 4
     base = N_SLOTS if sharded else 0
     batch, valid, _ = _wc_window(7, n_keys=2 * N_SLOTS if sharded else 8,
@@ -286,15 +295,45 @@ def _check_engine_grouping(mode, sharded):
     if sharded:
         kw = dict(owned=(batch.keys >= base) & (batch.keys < base + N_SLOTS),
                   slot_base=jnp.int32(base))
-    outs = []
-    for plane in ({}, dict(alive=jnp.ones(n_cns, bool))):
-        state = populate(cfg, store_init(cfg), np.arange(0, N_SLOTS, 2),
-                         np.arange(N_SLOTS // 2))
-        outs.append(apply_batch(cfg, state, credit_init(64), batch,
-                                valid=valid, **kw, **plane))
-    jax.tree.map(lambda a, b: np.testing.assert_array_equal(np.asarray(a),
-                                                            np.asarray(b)),
-                 outs[0], outs[1])
+    state = populate(cfg, store_init(cfg), np.arange(0, N_SLOTS, 2),
+                     np.arange(N_SLOTS // 2))
+    cr0 = credit_init(64)
+    cr0 = dataclasses.replace(cr0, credit=cr0.credit.at[::2].set(100))
+    _, credits, res, io = apply_batch(cfg, state, cr0, batch, valid=valid,
+                                      **kw)
+    kinds, keys, cn, pos = (np.asarray(x) for x in (batch.kinds, batch.keys,
+                                                    batch.cn, batch.pos))
+    live = np.asarray(valid) & (kinds != OpKind.NOP)
+    if sharded:
+        live &= np.asarray(kw["owned"])
+    pess = np.asarray(res.pessimistic)
+    upd, ins = live & (kinds == OpKind.UPDATE), live & (kinds == OpKind.INSERT)
+    dele = live & (kinds == OpKind.DELETE)
+    if mode in (SyncMode.SPIN, SyncMode.MCS):
+        np.testing.assert_array_equal(pess, upd)
+        loc = _last_per_group(keys, cn, pos, pess)
+        combined, writes = np.zeros_like(upd), ins.sum() + loc.sum()
+        rank = _queue_rank(keys, pos, loc)
+    else:
+        assert pess.any() == (mode == SyncMode.CIDER)
+        opt = upd & ~pess
+        loc = _last_per_group(keys, cn, pos, opt)
+        executor = _last_per_group(keys, 0 * cn, pos, pess)   # global WC
+        combined = (opt & ~loc) | (pess & ~executor)
+        writes = ins.sum() + loc.sum() + executor.sum()
+        queue = loc | dele if mode == SyncMode.OSYNC else loc
+        rank = _queue_rank(keys, pos, queue) + _queue_rank(keys, pos, pess)
+    assert loc.sum() < (opt if mode in (SyncMode.OSYNC, SyncMode.CIDER)
+                        else pess).sum()           # local WC combined some
+    np.testing.assert_array_equal(np.asarray(res.combined), combined)
+    np.testing.assert_array_equal(np.asarray(res.rank), rank)
+    assert int(io.writes) == int(writes)
+    if sharded and mode == SyncMode.CIDER:
+        cfg1 = _cfg(mode, n_slots=2 * N_SLOTS)
+        _, credits1, _, _ = apply_batch(cfg1, store_init(cfg1), cr0, batch,
+                                        valid=valid)
+        jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b)), credits, credits1)
 
 
 def _check_recovery_grouping(mode):
@@ -333,12 +372,76 @@ def _check_recovery_grouping(mode):
     assert int(tot.sum()) > int(tot[repaired].sum()) > 0   # both outcomes seen
 
 
+def _check_dead_tail_commit(mode):
+    """A CN that dies this window owns the last lane of key runs that
+    commit: the commits still land at the run's last completing lane, so
+    the store view, ``ptr``, ``ver`` and heap equal those of the all-alive
+    window without that CN's lanes."""
+    n_cns, dead_cn = 4, 3
+    batch, valid, _ = _wc_window(13, n_keys=8, n_cns=n_cns)
+    pos = np.asarray(batch.pos)
+    # the dying CN issues exactly the window's 16 latest lanes
+    cn = np.where(pos >= B - 16, dead_cn, np.asarray(batch.cn) % dead_cn)
+    batch = dataclasses.replace(batch, cn=jnp.asarray(cn, jnp.int32))
+    dead = cn == dead_cn
+    cfg = _cfg(mode, n_slots=N_SLOTS)
+    state0 = populate(cfg, store_init(cfg), np.arange(0, N_SLOTS, 2),
+                      np.arange(N_SLOTS // 2))
+    died = np.arange(n_cns) == dead_cn
+    crash = apply_batch(cfg, state0, credit_init(64), batch, valid=valid,
+                        alive=jnp.asarray(~died), died=jnp.asarray(died))
+    ref = apply_batch(cfg, state0, credit_init(64), batch,
+                      valid=valid & jnp.asarray(~dead),
+                      alive=jnp.ones(n_cns, bool), died=jnp.zeros(n_cns, bool))
+    (st_c, _, res_c, _), (st_r, _, res_r, _) = crash, ref
+    keys = np.asarray(batch.keys)
+    issued = np.asarray(valid) & (np.asarray(batch.kinds) != OpKind.NOP)
+    dead_last = [k for k in set(keys[issued].tolist())
+                 if dead[issued & (keys == k)][np.argmax(
+                     pos[issued & (keys == k)])]]
+    moved = np.asarray(st_r.ptr) != np.asarray(state0.ptr)
+    assert moved[dead_last].any()        # such a run commits
+    for a, b in zip(store_view(st_c), store_view(st_r)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for f in ("ptr", "ver", "heap", "heap_top"):
+        np.testing.assert_array_equal(np.asarray(getattr(st_c, f)),
+                                      np.asarray(getattr(st_r, f)), err_msg=f)
+    for f in ("ok", "value"):
+        np.testing.assert_array_equal(np.asarray(getattr(res_c, f)),
+                                      np.asarray(getattr(res_r, f)), err_msg=f)
+
+
 @pytest.mark.parametrize("case", [
     ("combine", 0, 1), ("combine", 1, 4), ("combine", 2, 16), ("combine", 3, 4),
     *[("engine", m, sharded) for m in MODES for sharded in (False, True)],
     ("recovery", SyncMode.MCS), ("recovery", SyncMode.SPIN),
+    *[("dead_tail", m) for m in MODES],
 ], ids=str)
 def test_local_wc_grouping_matches_sort(case):
     kind, *args = case
     {"combine": _check_combine_grouping, "engine": _check_engine_grouping,
-     "recovery": _check_recovery_grouping}[kind](*args)
+     "recovery": _check_recovery_grouping,
+     "dead_tail": _check_dead_tail_commit}[kind](*args)
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+@pytest.mark.parametrize("mode", MODES)
+def test_apply_batch_sorts_the_window_once(mode, sharded):
+    """With a CN dying in the window and ``scan_max`` 0, apply_batch's jaxpr
+    holds exactly one sort, step 1's (key, pos) plan, sharded or not: local
+    WC, the crash plane and the full-window credit feedback are masks over
+    that plan."""
+    from repro.analysis.jaxpr_check import _cfg as _jcfg
+    from repro.analysis.jaxpr_check import _engine_args, iter_eqns
+    cfg = _jcfg(mode)
+    args = _engine_args(cfg)
+    if sharded:
+        keys = args[2].keys
+        args += (keys >= 8, jnp.int32(8))
+
+    def fn(st, cr, b, v, a, d, owned=None, slot_base=None):
+        return apply_batch(cfg, st, cr, b, valid=v, alive=a, died=d,
+                           owned=owned, slot_base=slot_base)
+    closed = jax.make_jaxpr(fn)(*args)
+    sorts = [e for e, _ in iter_eqns(closed) if e.primitive.name == "sort"]
+    assert len(sorts) == 1

@@ -133,6 +133,9 @@ def test_make_stream_matches_opbatch_make():
     for w in range(W):
         ref = OpBatch.make(kinds[w], keys[w], values[w], n_cns=N_CNS)
         for f in dataclasses.fields(OpBatch):
+            if f.metadata.get("static"):
+                assert getattr(stream.batch, f.name) == getattr(ref, f.name)
+                continue
             np.testing.assert_array_equal(
                 np.asarray(getattr(stream.batch, f.name))[w],
                 np.asarray(getattr(ref, f.name)), err_msg=f.name)
